@@ -31,7 +31,9 @@ def cmd_status(args) -> int:
         _print(st)
         return 0
     print(f"Agent:     {st['node']} v{st['version']} "
-          f"(backend={st['backend']}, up {st['uptime-seconds']}s)")
+          f"(backend={st['backend']} on {st['device-count']}x "
+          f"{st['device-kind']} [{st['platform']}], "
+          f"up {st['uptime-seconds']}s)")
     print(f"Policy:    revision {st['policy-revision']}, "
           f"{st['identities']} identities, "
           f"{st['ipcache-entries']} ipcache entries")
@@ -1214,6 +1216,7 @@ def cmd_daemon(args) -> int:
     from ..agent.config import load_config
     from ..agent.daemon import Daemon
     from ..api.server import APIServer
+    from ..infra.jaxcache import enable_compile_cache
 
     # resolution order (agent/config.py): defaults < --config-dir
     # files < CILIUM_TPU_* env < explicit CLI flags
@@ -1240,6 +1243,7 @@ def cmd_daemon(args) -> int:
         "flow_agg_enabled": args.flow_agg,
     }.items() if v is not None}
     cfg = load_config(config_dir=args.config_dir, **overrides)
+    enable_compile_cache()
     d = Daemon(cfg)
     if args.state_dir and d.restore(args.state_dir):
         print(f"restored state from {args.state_dir}")

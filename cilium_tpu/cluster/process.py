@@ -100,6 +100,25 @@ def spawn_available() -> bool:
         return False
 
 
+def _refuse_accelerator_workers(config) -> None:
+    """A chip belongs to one process, and this parent already holds
+    every accelerator JAX found: a spawned tpu-backend worker would
+    either fail to load the TPU library or wait out
+    ``READY_TIMEOUT_S``.  Refuse at spawn instead.  On the cpu platform
+    workers share the host, as the tests run them."""
+    if config.backend != "tpu":
+        return
+    import jax
+
+    devices = jax.devices()
+    if devices[0].platform != "cpu":
+        raise RuntimeError(
+            f"process-mode cluster workers need a chip each, but this "
+            f"process holds all {len(devices)} {devices[0].platform} "
+            f"device(s) of the host; use thread mode, or "
+            f"backend='interpreter' workers")
+
+
 class ProcessNodeSpawner:
     """Owns the cluster's rendezvous listener and spawns workers.
 
@@ -131,6 +150,7 @@ class ProcessNodeSpawner:
 
         from .nodehost import node_host_main
 
+        _refuse_accelerator_workers(config)
         # the worker's daemon must self-identify as ITS node (thread
         # mode does the same via dataclasses.replace): the flight
         # recorder stamps bundles with it, and a cluster sysdump

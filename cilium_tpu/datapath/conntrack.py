@@ -404,7 +404,9 @@ def ct_update(ct: CTTable, hdr: jnp.ndarray, fwd: jnp.ndarray,
             trying = trying & also_try
         rows = jnp.where(trying, s, capacity)
         table = table.at[rows].set(new_row, mode="drop")
-        won = trying & jnp.all(table[s, :KEY_WORDS] == fwd, axis=1)
+        # gather whole rows, then slice: a partial-row gather
+        # (table[s, :KEY_WORDS]) lowers to a serial loop on the TPU
+        won = trying & jnp.all(table[s][:, :KEY_WORDS] == fwd, axis=1)
         fp = fp.at[jnp.where(won, s, capacity)].set(key_fp, mode="drop")
         return table, fp, pending & ~won
 

@@ -28,12 +28,8 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from ..core.packets import (
     COL_DPORT,
@@ -48,8 +44,15 @@ from ..datapath.verdict import DatapathState, datapath_step
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "data") -> Mesh:
+    """1-D mesh over the first ``n_devices`` devices (all by default).
+    Raises when fewer exist: a smaller mesh would run a different
+    deployment under the requested name."""
     devs = jax.devices()
     if n_devices is not None:
+        if len(devs) < n_devices:
+            raise ValueError(
+                f"make_mesh({n_devices}) needs {n_devices} devices; "
+                f"{len(devs)} {devs[0].platform} device(s) present")
         devs = devs[:n_devices]
     return Mesh(np.asarray(devs), (axis,))
 

@@ -291,6 +291,17 @@ class ServingRuntime:
         with self._rec_lock:
             return self._gen == gen
 
+    def exempt_inflight(self) -> None:
+        # thread-affinity: drain
+        """The dispatch in flight is about to pay a compile that is
+        not its own shape's (a drain window's first gather of a ring
+        rung, compiled inside the dispatch's drain tick): exempt it
+        from the deadline, as a cold shape is."""
+        with self._rec_lock:
+            if self._inflight is not None:
+                self._inflight = (self._inflight[:3] + (True,)
+                                  + self._inflight[4:])
+
     def reset_warm_shapes(self) -> None:
         # thread-affinity: drain, api
         """Forget which shapes have compiled — call after a dispatch

@@ -41,6 +41,51 @@ def _pod_ip(i: int) -> str:
     return f"10.{(i >> 16) & 0xFF}.{(i >> 8) & 0xFF}.{i & 0xFF}"
 
 
+def baseline_rules(n_identities: int = 10_000,
+                   n_rules: int = 64) -> List[dict]:
+    """The db endpoint's rule set of BASELINE.md config #3: ``n_rules``
+    selector allows over slices of the svc identities on assorted port
+    ranges, a broad namespace allow, a CIDR range, one deny, one L7
+    redirect and an egress DNS allow — every verdict class."""
+    # rule set: each rule allows one "service group" label slice on a
+    # port range; every identity matches ns=default so selector slices
+    # use app labels
+    rules: List[dict] = []
+    group = max(n_identities // n_rules, 1)
+    for r in range(n_rules):
+        ports = [{"port": str(1000 + r * 7), "protocol": "TCP",
+                  "endPort": 1000 + r * 7 + 5}]
+        sel = {"matchLabels": {"app": f"svc{r * group}"}}
+        rules.append({
+            "endpointSelector": {"matchLabels": {"app": "db"}},
+            "ingress": [
+                {"fromEndpoints": [sel], "toPorts": [{"ports": ports}]},
+            ],
+        })
+    rules.append({
+        "endpointSelector": {"matchLabels": {"app": "db"}},
+        "ingress": [
+            # broad: everyone in the namespace may reach 5432/TCP
+            {"fromEndpoints": [{"matchLabels": {"ns": "default"}}],
+             "toPorts": [{"ports": [{"port": "5432", "protocol": "TCP"}]}]},
+            {"fromCIDR": ["192.168.0.0/16"],
+             "toPorts": [{"ports": [{"port": "8000", "endPort": 8999}]}]},
+            {"fromEndpoints": [{"matchLabels": {"ns": "default"}}],
+             "toPorts": [{"ports": [{"port": "80", "protocol": "TCP"}],
+                          "rules": {"http": [{"method": "GET"}]}}]},
+        ],
+        "ingressDeny": [
+            {"fromEndpoints": [{"matchLabels": {"app": "svc0"}}],
+             "toPorts": [{"ports": [{"port": "22", "protocol": "TCP"}]}]},
+        ],
+        "egress": [
+            {"toEntities": ["world"],
+             "toPorts": [{"ports": [{"port": "53", "protocol": "UDP"}]}]},
+        ],
+    })
+    return rules
+
+
 def build_world(n_identities: int = 10_000, n_rules: int = 64,
                 ct_capacity: int = 1 << 20, ct_shards: int = 1,
                 row_capacity: Optional[int] = None,
@@ -81,43 +126,7 @@ def build_world(n_identities: int = 10_000, n_rules: int = 64,
     if n_v6:
         ipcache["::/0"] = world_id
 
-    # rule set: each rule allows one "service group" label slice on a
-    # port range; every identity matches ns=default so selector slices
-    # use app labels
-    rules: List[dict] = []
-    group = max(n_identities // n_rules, 1)
-    for r in range(n_rules):
-        ports = [{"port": str(1000 + r * 7), "protocol": "TCP",
-                  "endPort": 1000 + r * 7 + 5}]
-        sel = {"matchLabels": {"app": f"svc{r * group}"}}
-        rules.append({
-            "endpointSelector": {"matchLabels": {"app": "db"}},
-            "ingress": [
-                {"fromEndpoints": [sel], "toPorts": [{"ports": ports}]},
-            ],
-        })
-    rules.append({
-        "endpointSelector": {"matchLabels": {"app": "db"}},
-        "ingress": [
-            # broad: everyone in the namespace may reach 5432/TCP
-            {"fromEndpoints": [{"matchLabels": {"ns": "default"}}],
-             "toPorts": [{"ports": [{"port": "5432", "protocol": "TCP"}]}]},
-            {"fromCIDR": ["192.168.0.0/16"],
-             "toPorts": [{"ports": [{"port": "8000", "endPort": 8999}]}]},
-            {"fromEndpoints": [{"matchLabels": {"ns": "default"}}],
-             "toPorts": [{"ports": [{"port": "80", "protocol": "TCP"}],
-                          "rules": {"http": [{"method": "GET"}]}}]},
-        ],
-        "ingressDeny": [
-            {"fromEndpoints": [{"matchLabels": {"app": "svc0"}}],
-             "toPorts": [{"ports": [{"port": "22", "protocol": "TCP"}]}]},
-        ],
-        "egress": [
-            {"toEntities": ["world"],
-             "toPorts": [{"ports": [{"port": "53", "protocol": "UDP"}]}]},
-        ],
-    })
-    repo.add_obj(rules)
+    repo.add_obj(baseline_rules(n_identities, n_rules))
     pol_db = repo.resolve(db)
 
     if row_capacity is None:
@@ -268,4 +277,82 @@ def bench_traffic(world: World, n: int, rng: np.random.Generator,
     out[:, COL_FAMILY] = 4
     out[:, COL_EP] = 0
     out[:, COL_DIR] = 0
+    return out
+
+
+def verdict_mix_traffic(pod_ips: List[str], dst_ip: str,
+                        rng: np.random.Generator, n: int, ep: int = 0,
+                        egress_frac: float = 0.15,
+                        related_frac: float = 0.03) -> np.ndarray:
+    """Randomized IPv4 rows hitting every verdict class of the
+    BASELINE config #3 policy toward ``dst_ip`` (endpoint ``ep``):
+    /32 pod sources (85%), the 192.168/16 CIDR range (10%), world
+    (5%); allowed, denied, port-range, L7-redirect, ICMP and OTHER
+    traffic over a bounded flow space so flows recur (CT churn across
+    the SYN/EST/CLOSING lifecycle).  ``egress_frac`` of the rows are
+    egress (DNS and denied 443 to world); ``related_frac`` are ICMP
+    errors carrying an embedded tuple (CT_RELATED when the flow
+    exists).  With both at 0 every row is one ingress stream whose
+    verdict does not depend on packet order."""
+    import ipaddress
+
+    from ..core.packets import (COL_DIR, COL_DPORT, COL_DST_IP3, COL_EP,
+                                COL_FAMILY, COL_FLAGS, COL_LEN,
+                                COL_PROTO, COL_SPORT, COL_SRC_IP3,
+                                FLAG_RELATED, N_COLS, TCP_ACK, TCP_FIN,
+                                TCP_RST, TCP_SYN)
+
+    out = np.zeros((n, N_COLS), dtype=np.uint32)
+    pod_ints = np.array([int(ipaddress.IPv4Address(ip)) for ip in pod_ips],
+                        dtype=np.uint32)
+    dst_int = int(ipaddress.IPv4Address(dst_ip))
+    kind = rng.random(n)
+    src = rng.choice(pod_ints, n)
+    cidr_ips = (0xC0A80000 + rng.integers(1, 1 << 16, n)).astype(np.uint32)
+    ext_ips = rng.choice(np.array([0x08080808, 0x01010101, 0x0B0B0B0B],
+                                  dtype=np.uint32), n)
+    src = np.where(kind < 0.85, src, np.where(kind < 0.95, cidr_ips,
+                                              ext_ips))
+    out[:, COL_SRC_IP3] = src
+    out[:, COL_DST_IP3] = dst_int
+    out[:, COL_SPORT] = 1024 + rng.integers(0, 2000, n, dtype=np.uint32)
+    out[:, COL_DPORT] = rng.choice(np.array(
+        [5432, 5432, 80, 22, 1007, 1014, 8080, 8443, 443, 53],
+        dtype=np.uint32), n)
+    out[:, COL_PROTO] = rng.choice(
+        np.array([6, 6, 6, 6, 17, 1, 47], dtype=np.uint32), n)
+    is_tcp = out[:, COL_PROTO] == 6
+    out[:, COL_FLAGS] = np.where(
+        is_tcp,
+        rng.choice(np.array([TCP_SYN, TCP_ACK, TCP_ACK, TCP_ACK | TCP_FIN,
+                             TCP_RST], dtype=np.uint32), n),
+        0)
+    # ICMP: echo request/reply types in the dport column, no ports
+    is_icmp = out[:, COL_PROTO] == 1
+    out[:, COL_SPORT] = np.where(is_icmp, 0, out[:, COL_SPORT])
+    out[:, COL_DPORT] = np.where(
+        is_icmp, rng.integers(0, 2, n, dtype=np.uint32) * 8,
+        out[:, COL_DPORT])
+    out[:, COL_LEN] = rng.integers(60, 1500, n, dtype=np.uint32)
+    out[:, COL_FAMILY] = 4
+    out[:, COL_EP] = ep
+    # egress flips the remote to dst, so egress rows get a world dst
+    egress = rng.random(n) < egress_frac
+    out[:, COL_DIR] = egress.astype(np.uint32)
+    out[:, COL_DST_IP3] = np.where(egress, ext_ips, out[:, COL_DST_IP3])
+    out[:, COL_DPORT] = np.where(
+        egress & ~is_icmp,
+        rng.choice(np.array([53, 53, 443], dtype=np.uint32), n),
+        out[:, COL_DPORT])
+    out[:, COL_PROTO] = np.where(
+        egress & (out[:, COL_DPORT] == 53), 17, out[:, COL_PROTO])
+    out[:, COL_FLAGS] = np.where(out[:, COL_PROTO] != 6, 0,
+                                 out[:, COL_FLAGS])
+    # the embedded tuple reuses the row's own 5-tuple space, so some
+    # RELATED rows hit live CT entries and the rest miss
+    related = rng.random(n) < related_frac
+    out[:, COL_FLAGS] = np.where(related, FLAG_RELATED,
+                                 out[:, COL_FLAGS])
+    out[:, COL_PROTO] = np.where(related & (out[:, COL_PROTO] == 47),
+                                 6, out[:, COL_PROTO])
     return out

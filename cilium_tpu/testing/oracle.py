@@ -324,3 +324,17 @@ class OracleDatapath:
         for k in dead:
             del self.ct[k]
         return len(dead)
+
+
+def count_divergent(out: np.ndarray, want: Sequence[OracleResult],
+                    row_to_numeric: np.ndarray) -> Tuple[int, List[int]]:
+    """Rows where the device step's ``out`` ([N, >=6]: verdict, proxy,
+    ct, identity ROW, reason, event) disagrees with the oracle's
+    results.  Returns (count, indices of the first five)."""
+    out = np.asarray(out)
+    exp = np.array([(w.verdict, w.proxy, w.ct, w.identity, w.reason,
+                     w.event) for w in want], dtype=np.int64).reshape(-1, 6)
+    got = out[:, :6].astype(np.int64)
+    got[:, 3] = np.asarray(row_to_numeric)[got[:, 3]]
+    bad = np.nonzero((got != exp).any(axis=1))[0]
+    return int(len(bad)), [int(i) for i in bad[:5]]

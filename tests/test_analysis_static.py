@@ -895,7 +895,8 @@ class TestLiveRepo:
                         ignore=shutil.ignore_patterns("__pycache__"))
         daemon = dst / "agent" / "daemon.py"
         src = daemon.read_text()
-        marker = 'window, s["ring"] = s["drainer"].swap_window(s["ring"])'
+        marker = ('window, s["ring"] = s["drainer"].swap_window('
+                  's["ring"], on_cold)')
         assert marker in src
         src = src.replace(marker, marker + """
         from ..monitor.api import decode_ring_rows

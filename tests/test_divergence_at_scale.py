@@ -8,103 +8,20 @@ redirect, ICMP, OTHER-proto traffic, egress DNS, CT churn across the
 SYN/EST/CLOSING lifecycle, and interleaved GC sweeps on both sides.
 """
 
-import ipaddress
-
 import jax.numpy as jnp
 import numpy as np
 
-from cilium_tpu.core.packets import (
-    COL_DIR,
-    COL_DPORT,
-    COL_DST_IP3,
-    COL_EP,
-    COL_FAMILY,
-    COL_FLAGS,
-    COL_LEN,
-    COL_PROTO,
-    COL_SPORT,
-    COL_SRC_IP3,
-    N_COLS,
-    TCP_ACK,
-    TCP_FIN,
-    TCP_RST,
-    TCP_SYN,
-    HeaderBatch,
-)
+from cilium_tpu.core.packets import HeaderBatch
 from cilium_tpu.datapath import datapath_step_jit
 from cilium_tpu.datapath.conntrack import ct_gc_jit
 from cilium_tpu.datapath.verdict import DatapathState
 from cilium_tpu.testing import OracleDatapath
-from cilium_tpu.testing.fixtures import build_world
+from cilium_tpu.testing.oracle import count_divergent
+from cilium_tpu.testing.fixtures import build_world, verdict_mix_traffic
 
 N_IDENTITIES = 10_000
 BATCH = 4096
 N_BATCHES = 25  # 102,400 packets total
-
-
-def _traffic(world, rng, n):
-    """Randomized batch hitting every verdict class of the 10k world."""
-    out = np.zeros((n, N_COLS), dtype=np.uint32)
-    pod_ints = np.array([int(ipaddress.IPv4Address(ip))
-                         for ip in world.pod_ips], dtype=np.uint32)
-    # src mix: pods (85%), CIDR range (10%), external/world (5%)
-    kind = rng.random(n)
-    src = rng.choice(pod_ints, n)
-    cidr_ips = (0xC0A80000 + rng.integers(1, 1 << 16, n)).astype(np.uint32)
-    ext_ips = rng.choice(np.array([0x08080808, 0x01010101, 0x0B0B0B0B],
-                                  dtype=np.uint32), n)
-    src = np.where(kind < 0.85, src, np.where(kind < 0.95, cidr_ips,
-                                              ext_ips))
-    db_ip = int(ipaddress.IPv4Address(world.pod_ips[0]))
-    out[:, COL_SRC_IP3] = src
-    out[:, COL_DST_IP3] = db_ip
-    # moderate flow space so flows recur across batches (CT churn)
-    out[:, COL_SPORT] = 1024 + (rng.integers(0, 2000, n, dtype=np.uint32))
-    out[:, COL_DPORT] = rng.choice(np.array(
-        [5432, 5432, 80, 22, 1007, 1014, 8080, 8443, 443, 53], dtype=np.uint32), n)
-    out[:, COL_PROTO] = rng.choice(
-        np.array([6, 6, 6, 6, 17, 1, 47], dtype=np.uint32), n)
-    is_tcp = out[:, COL_PROTO] == 6
-    out[:, COL_FLAGS] = np.where(
-        is_tcp,
-        rng.choice(np.array([TCP_SYN, TCP_ACK, TCP_ACK, TCP_ACK | TCP_FIN,
-                             TCP_RST], dtype=np.uint32), n),
-        0)
-    # ICMP: echo request/reply types in the dport column, no ports
-    is_icmp = out[:, COL_PROTO] == 1
-    out[:, COL_SPORT] = np.where(is_icmp, 0, out[:, COL_SPORT])
-    out[:, COL_DPORT] = np.where(
-        is_icmp, rng.integers(0, 2, n, dtype=np.uint32) * 8,
-        out[:, COL_DPORT])
-    out[:, COL_LEN] = rng.integers(60, 1500, n, dtype=np.uint32)
-    out[:, COL_FAMILY] = 4
-    out[:, COL_EP] = 0
-    # ~15% egress (DNS to world etc.); egress flips the remote to dst,
-    # so give egress packets an external dst
-    egress = rng.random(n) < 0.15
-    out[:, COL_DIR] = egress.astype(np.uint32)
-    out[:, COL_DST_IP3] = np.where(egress, ext_ips, out[:, COL_DST_IP3])
-    out[:, COL_DPORT] = np.where(
-        egress & ~is_icmp,
-        rng.choice(np.array([53, 53, 443], dtype=np.uint32), n),
-        out[:, COL_DPORT])
-    out[:, COL_PROTO] = np.where(
-        egress & (out[:, COL_DPORT] == 53), 17, out[:, COL_PROTO])
-    out[:, COL_FLAGS] = np.where(out[:, COL_PROTO] != 6, 0,
-                                 out[:, COL_FLAGS])
-    # ~3% RELATED rows: ICMP errors whose columns carry an embedded
-    # tuple (what the ingest parser produces for dest-unreachable
-    # etc.) — some relate to flows that exist, some to nothing
-    from cilium_tpu.core.packets import FLAG_RELATED
-
-    related = rng.random(n) < 0.03
-    out[:, COL_FLAGS] = np.where(related, FLAG_RELATED,
-                                 out[:, COL_FLAGS])
-    # the embedded tuple reuses the row's own 5-tuple space, so a
-    # fraction will hit live CT entries (CT_RELATED) and the rest miss
-    out[:, COL_PROTO] = np.where(related & (out[:, COL_PROTO] == 47),
-                                 6, out[:, COL_PROTO])
-    return out
 
 
 def test_10k_identity_divergence_gate():
@@ -118,23 +35,17 @@ def test_10k_identity_divergence_gate():
     total = 0
     n_div = 0
     for b in range(N_BATCHES):
-        data = _traffic(world, rng, BATCH)
+        data = verdict_mix_traffic(world.pod_ips, world.pod_ips[0],
+                                   rng, BATCH)
         out, state = datapath_step_jit(state, jnp.asarray(data),
                                        jnp.uint32(now))
-        out = np.asarray(out)
         want = oracle.step(HeaderBatch(data), now)
-        for i, w in enumerate(want):
-            got = (int(out[i, 0]), int(out[i, 1]), int(out[i, 2]),
-                   int(row_to_numeric[out[i, 3]]), int(out[i, 4]),
-                   int(out[i, 5]))
-            exp = (w.verdict, w.proxy, w.ct, w.identity, w.reason,
-                   w.event)
-            if got != exp:
-                n_div += 1
-                if n_div <= 5:
-                    print(f"DIVERGE batch {b} pkt {i}: "
-                          f"{HeaderBatch(data).describe(i)}\n"
-                          f"  got  {got}\n  want {exp}")
+        bad, first = count_divergent(np.asarray(out), want, row_to_numeric)
+        for i in first:
+            print(f"DIVERGE batch {b} pkt {i}: "
+                  f"{HeaderBatch(data).describe(i)}\n  got  "
+                  f"{np.asarray(out)[i, :6]}\n  want {want[i]}")
+        n_div += bad
         total += len(want)
         # clock advance: occasionally jump past the SYN lifetime so
         # half-open flows expire; GC both sides in lockstep

@@ -301,6 +301,41 @@ class TestHangDetection:
         d.shutdown()
 
 
+    def test_first_gather_compile_in_a_dispatch_is_not_a_hang(
+            self, monkeypatch):
+        """A drain tick inside a warm-shape dispatch may compile the
+        window's gather rung for the first time.  That compile (a
+        400 ms stand-in under a 150 ms deadline) exempts the dispatch
+        as a cold shape's compile does, instead of reading as a hang
+        that drops the batch."""
+        from cilium_tpu.monitor import ring
+
+        real, seen = ring.ring_gather, set()
+
+        def slow_first(buf, starts, rung, cap):
+            if (len(starts), rung, cap) not in seen:
+                seen.add((len(starts), rung, cap))
+                time.sleep(0.4)
+            return real(buf, starts, rung, cap)
+
+        monkeypatch.setattr(ring, "_WARM_GATHERS", set())
+        monkeypatch.setattr(ring, "ring_gather", slow_first)
+        d, db = _daemon(serving_dispatch_deadline_ms=150.0)
+        d.start_serving(trace_sample=0, ingress=True, drain_every=2)
+        rt = d._serving["runtime"]
+        # one doorbell of 6 buckets: the loop dispatches them back to
+        # back, so the first tick runs inside the third (warm) dispatch
+        d.submit(np.concatenate([_fwd(db.id, base=20000 + 64 * i)
+                                 for i in range(6)]))
+        assert _wait(lambda: rt.stats.verdicts >= 6 * 64, timeout=30)
+        fe = d.stop_serving()["front-end"]
+        d.shutdown()
+        ft = _assert_ledger(fe)
+        assert seen
+        assert ft["dispatch-timeouts"] == 0
+        assert rt.stats.restarts == 0
+
+
 # ---------------------------------------------------------------------
 class TestRestartBudget:
     def test_budget_exhaustion_goes_terminal_with_exact_ledger(self):

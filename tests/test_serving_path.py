@@ -136,6 +136,50 @@ class TestServingPath:
             d.start_serving()
 
 
+class TestDefaultRingSize:
+    """start_serving's default ring holds a whole window of the top
+    rung even when every row is an event: a row appends at most one."""
+
+    @pytest.mark.parametrize("ladder,kw,want", [
+        ((1024, 4096, 16384, 65536), {}, 4 * 65536),
+        ((1024, 4096, 16384, 65536), {"drain_every": 2}, 2 * 65536),
+        ((1024, 65536), {"superbatch_k": 2}, 1 << 19),  # 5 top rungs
+        ((256, 1024), {}, 1 << 15),  # never below 2^15
+        ((1024, 65536), {"ring_capacity": 1 << 10}, 1 << 10),
+    ])
+    def test_capacity(self, ladder, kw, want):
+        d = Daemon(DaemonConfig(backend="tpu", ct_capacity=1 << 12,
+                                serving_bucket_ladder=ladder,
+                                serving_queue_depth=ladder[-1]))
+        try:
+            d.start_serving(trace_sample=0, **kw)
+            assert d.serving_stats()["ring"]["capacity"] == want
+            d.stop_serving()
+        finally:
+            d.shutdown()
+
+    def test_all_drop_window_of_the_top_rung_loses_nothing(self):
+        # 4 batches x 16384 drops fill one window: 2x the old fixed
+        # 2^15 default, which overwrote half of them
+        top = 1 << 14
+        d = Daemon(DaemonConfig(backend="tpu", ct_capacity=1 << 12,
+                                serving_bucket_ladder=(1024, top),
+                                serving_queue_depth=top))
+        d.add_endpoint("web", ("10.0.1.1",), ["k8s:app=web"])
+        db = d.add_endpoint("db", ("10.0.2.1",), ["k8s:app=db"])
+        d.policy_import(RULES)
+        drops = np.repeat(_traffic(db.id, 20000, n=2)[1:], top, axis=0)
+        try:
+            d.start_serving(trace_sample=0)
+            for i in range(4):
+                d.serve_batch(drops, now=10 + i)
+            stats = d.stop_serving()
+        finally:
+            d.shutdown()
+        assert stats["lost"] == 0
+        assert stats["events"] == 4 * top
+
+
 class TestServingAcrossRegeneration:
     def test_identity_churn_mid_serving_window(self):
         """Identity churn between serving batches: events of a
