@@ -2807,32 +2807,38 @@ class Daemon:
         and the spans accumulated since the last tick — onto the
         worker's bounded queue.  No d2h buffer wait, no decode, no
         wide-column join, no monitor fan-out here; a queue overflow
-        drops the window COUNTED (never silently)."""
+        drops the window COUNTED (never silently).  Profiler span
+        ``drain_tick``, under the id of the batch about to dispatch."""
+        from ..obs.trace import profile_span
         from ..serving.eventplane import DrainWindow
 
-        # a first gather compile inside a dispatch is not a hang
-        on_cold = getattr(s.get("runtime"), "exempt_inflight", None)
-        window, s["ring"] = s["drainer"].swap_window(s["ring"], on_cold)
-        s["last_tick"] = s["seq"]
-        spans, s["spans"] = s["spans"], {}
-        # shallow snapshot: the window keeps the records (arena slot
-        # + numerics references) alive on the worker regardless of
-        # the pruning below — zero copy, the ownership-horizon shape
-        records = dict(s["window"])
-        s["eventplane"].submit(DrainWindow(
-            window, records, spans, s["n_shards"],
-            tracer=s.get("tracer"), seq=s["seq"]))
-        # retain headers for the batches filling the next window plus
-        # one horizon of slack; in-flight windows hold their own refs.
-        # A superbatch advances seq by K in one dispatch, so a window
-        # spans up to drain_every + K - 1 batch records — the
-        # retention stretches by the configured K ceiling
-        live = {(s["seq"] - 1 - i) & 0x1FFF
-                for i in range(2 * (s["drain_every"]
-                                    + s.get("superbatch_k", 1)))}
-        for b in list(s["window"]):
-            if b not in live:
-                del s["window"][b]
+        with profile_span("drain_tick", s["seq"] & 0x1FFF):
+            # a first gather compile inside a dispatch is not a hang
+            on_cold = getattr(s.get("runtime"), "exempt_inflight",
+                              None)
+            window, s["ring"] = s["drainer"].swap_window(s["ring"], on_cold)
+            s["last_tick"] = s["seq"]
+            spans, s["spans"] = s["spans"], {}
+            # shallow snapshot: the window keeps the records (arena
+            # slot + numerics references) alive on the worker
+            # regardless of the pruning below — zero copy, the
+            # ownership-horizon shape
+            records = dict(s["window"])
+            s["eventplane"].submit(DrainWindow(
+                window, records, spans, s["n_shards"],
+                tracer=s.get("tracer"), seq=s["seq"]))
+            # retain headers for the batches filling the next window
+            # plus one horizon of slack; in-flight windows hold their
+            # own refs.  A superbatch advances seq by K in one
+            # dispatch, so a window spans up to drain_every + K - 1
+            # batch records — the retention stretches by the
+            # configured K ceiling
+            live = {(s["seq"] - 1 - i) & 0x1FFF
+                    for i in range(2 * (s["drain_every"]
+                                        + s.get("superbatch_k", 1)))}
+            for b in list(s["window"]):
+                if b not in live:
+                    del s["window"][b]
 
     def _serving_event_idle_tick(self) -> None:
         # thread-affinity: drain
@@ -2879,9 +2885,15 @@ class Daemon:
         thread): finish the d2h transfer + decode, join packed rows
         back to wide columns, emit to monitor/hubble consumers, and
         stamp sampled spans at TRUE window-join time — device work is
-        provably complete once the window's fetch lands."""
+        provably complete once the window's fetch lands.  Profiler
+        spans ``fetch``, ``emit`` and ``analytics``, under the id of the
+        batch whose drain tick swapped the window."""
+        from ..obs.trace import profile_span
+
+        bid = -1 if dw.seq is None else dw.seq & 0x1FFF
         self._event_check_horizon(dw, self._serving)
-        rows, shards, _appended, _lost = dw.ring.fetch()
+        with profile_span("fetch", bid):
+            rows, shards, _appended, _lost = dw.ring.fetch()
         t_dev = time.monotonic()
         try:
             # the fetch itself can stall (a slow d2h): the producer
@@ -2890,7 +2902,9 @@ class Daemon:
             # still reference recycled slots by the time the rows
             # land — re-check before publishing anything
             self._event_check_horizon(dw, self._serving)
-            self._emit_ring_rows(rows, shards, dw.records, dw.n_shards)
+            with profile_span("emit", bid):
+                self._emit_ring_rows(rows, shards, dw.records,
+                                     dw.n_shards)
         except Exception:
             # fetch() already credited the drainer's delivered
             # counters; a refuse/emit failure means the monitor plane
@@ -2929,7 +2943,8 @@ class Daemon:
         # events were already delivered above, so an analytics fault
         # must not recount the window as a drop
         try:
-            self.analytics.drain()
+            with profile_span("analytics", bid):
+                self.analytics.drain()
         except Exception:  # noqa: BLE001
             logging.getLogger(__name__).warning(
                 "flow-analytics drain failed at window join",

@@ -27,6 +27,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs.trace import profile_span
 from ..policy.compiler import IdentityRowMap, compile_policy
 from ..policy.resolve import EndpointPolicy
 from .lpm import compile_lpm
@@ -320,6 +321,57 @@ class TPULoader(Loader):
             mode, tuple(shape), before, after, elapsed_s,
             key_extra=(int(ring_cap),) + tuple(statics)
             + (self.attach_count,))
+
+    def _stage(self, batch_id: int, rows, valid, scalars=(),
+               put=None) -> tuple:
+        # thread-affinity: drain, api
+        """h2d staging of one serving batch, before the lock (lock
+        discipline in __init__), in the profiler span ``h2d``: host
+        ``rows`` and ``valid`` onto the device by ``put`` (default
+        ``jnp.asarray``) and each of ``scalars`` as a u32.  Returns
+        ``(rows, valid, *scalars)``."""
+        jnp = self._jnp
+        put = put or jnp.asarray
+        with profile_span("h2d", batch_id):
+            if isinstance(rows, np.ndarray):
+                rows = put(np.ascontiguousarray(rows))
+            if isinstance(valid, np.ndarray):
+                valid = put(valid)
+            return (rows, valid) + tuple(jnp.uint32(x) for x in scalars)
+
+    def _dispatch(self, mode: str, batch_id: int, step, args: tuple,
+                  kw: dict, statics: tuple):
+        # thread-affinity: drain, api
+        # table-swap-ok: dispatch-result swap — CT/metrics advance,
+        # policy+ipcache references carried unchanged
+        """One serving dispatch: ``step(state, *args, **kw)`` ->
+        ``(state', ring')`` under the lock, which the agent's
+        controllers also take (profiler spans ``lock_wait`` for the
+        acquisition, ``dispatch`` for the async jit call and the state
+        swap).  A grown jit cache is recorded as a compile of
+        ``args[1]``'s shape.  Returns ``(ring', row_map)``."""
+        with profile_span("lock_wait", batch_id):
+            self._lock.acquire()
+        try:
+            with profile_span("dispatch", batch_id):
+                ring, row_map, before, after, t0 = self._dispatch_held(
+                    mode, step, args, kw)
+        finally:
+            self._lock.release()
+        if after > before:
+            self._record_compile(mode, args[1].shape, ring.buf.shape[0],
+                                 statics, before, after,
+                                 time.monotonic() - t0)
+        return ring, row_map
+
+    def _dispatch_held(self, mode: str, step, args: tuple, kw: dict):
+        # holds: datapath-loader
+        # table-swap-ok: dispatch-result swap (see _dispatch)
+        before = self._serving_cache_size(mode)
+        t0 = time.monotonic()
+        self.state, ring = step(self.state, *args, **kw)
+        return (ring, self.row_map, before,
+                self._serving_cache_size(mode), t0)
 
     def _rekeep_serving_placement(self) -> None:
         # holds: datapath-loader
@@ -741,30 +793,14 @@ class TPULoader(Loader):
         from ..monitor.ring import serve_step_jit
 
         faults.check(faults.SITE_LOADER_SERVE)
-        jnp = self._jnp
-        # staging before the lock: only the async dispatch is
-        # serialized (lock discipline in __init__)
-        if isinstance(hdr, np.ndarray):
-            hdr = jnp.asarray(np.ascontiguousarray(hdr))
-        if isinstance(valid, np.ndarray):
-            valid = jnp.asarray(valid)
-        now, batch_id = jnp.uint32(now), jnp.uint32(batch_id)
-        with self._lock:
-            before = self._serving_cache_size("wide")
-            t0 = time.monotonic()
-            self.state, ring = serve_step_jit(
-                self.state, ring, hdr, now, batch_id,
-                trace_sample=trace_sample,
-                valid=valid, proxy_ports=proxy_ports, audit=audit)
-            after = self._serving_cache_size("wide")
-            row_map = self.row_map
-        if after > before:
-            self._record_compile(
-                "wide", hdr.shape, ring.capacity,
-                (int(trace_sample), bool(audit),
-                 proxy_ports is not None, valid is not None),
-                before, after, time.monotonic() - t0)
-        return ring, row_map
+        hdr, valid, now, bid = self._stage(batch_id, hdr, valid,
+                                           (now, batch_id))
+        return self._dispatch(
+            "wide", batch_id, serve_step_jit, (ring, hdr, now, bid),
+            dict(trace_sample=trace_sample, valid=valid,
+                 proxy_ports=proxy_ports, audit=audit),
+            (int(trace_sample), bool(audit), proxy_ports is not None,
+             valid is not None))
 
     def serve_packed(self, ring, packed, now: int, batch_id: int,
                      ep: int, dirn: int, trace_sample: int = 1024,
@@ -784,29 +820,15 @@ class TPULoader(Loader):
         from ..monitor.ring import serve_step_packed_jit
 
         faults.check(faults.SITE_LOADER_SERVE_PACKED)
-        jnp = self._jnp
-        if isinstance(packed, np.ndarray):
-            packed = jnp.asarray(np.ascontiguousarray(packed))
-        if isinstance(valid, np.ndarray):
-            valid = jnp.asarray(valid)
-        now, batch_id = jnp.uint32(now), jnp.uint32(batch_id)
-        ep, dirn = jnp.uint32(ep), jnp.uint32(dirn)
-        with self._lock:
-            before = self._serving_cache_size("packed")
-            t0 = time.monotonic()
-            self.state, ring = serve_step_packed_jit(
-                self.state, ring, packed, now, batch_id, ep, dirn,
-                trace_sample=trace_sample, valid=valid,
-                proxy_ports=proxy_ports, audit=audit)
-            after = self._serving_cache_size("packed")
-            row_map = self.row_map
-        if after > before:
-            self._record_compile(
-                "packed", packed.shape, ring.capacity,
-                (int(trace_sample), bool(audit),
-                 proxy_ports is not None, valid is not None),
-                before, after, time.monotonic() - t0)
-        return ring, row_map
+        packed, valid, now, bid, ep, dirn = self._stage(
+            batch_id, packed, valid, (now, batch_id, ep, dirn))
+        return self._dispatch(
+            "packed", batch_id, serve_step_packed_jit,
+            (ring, packed, now, bid, ep, dirn),
+            dict(trace_sample=trace_sample, valid=valid,
+                 proxy_ports=proxy_ports, audit=audit),
+            (int(trace_sample), bool(audit), proxy_ports is not None,
+             valid is not None))
 
     def serve_superbatch(self, ring, hdr, now: int, batch_id0: int,
                          eps=None, dirns=None,
@@ -834,44 +856,19 @@ class TPULoader(Loader):
                                     serve_superbatch_packed_jit)
 
         faults.check(faults.SITE_LOADER_SERVE_SUPER)
-        jnp = self._jnp
-        # staging before the lock: only the async dispatch is
-        # serialized (lock discipline in __init__)
-        if isinstance(hdr, np.ndarray):
-            hdr = jnp.asarray(np.ascontiguousarray(hdr))
-        if isinstance(valid, np.ndarray):
-            valid = jnp.asarray(valid)
-        if packed:
-            eps = jnp.asarray(
-                np.ascontiguousarray(eps, dtype=np.uint32))
-            dirns = jnp.asarray(
-                np.ascontiguousarray(dirns, dtype=np.uint32))
-        now, batch_id0 = jnp.uint32(now), jnp.uint32(batch_id0)
-        mode = "super-packed" if packed else "super-wide"
-        with self._lock:
-            before = self._serving_cache_size(mode)
-            t0 = time.monotonic()
-            if packed:
-                self.state, ring = serve_superbatch_packed_jit(
-                    self.state, ring, hdr, now, batch_id0, eps,
-                    dirns, trace_sample=trace_sample, valid=valid,
-                    proxy_ports=proxy_ports, audit=audit)
-            else:
-                self.state, ring = serve_superbatch_jit(
-                    self.state, ring, hdr, now, batch_id0,
-                    trace_sample=trace_sample, valid=valid,
-                    proxy_ports=proxy_ports, audit=audit)
-            after = self._serving_cache_size(mode)
-            row_map = self.row_map
-        if after > before:
-            # hdr.shape is (K, bucket, cols): K rides the shape, so
-            # the one-executable invariant keys on (rung, mode, K)
-            self._record_compile(
-                mode, hdr.shape, ring.capacity,
-                (int(trace_sample), bool(audit),
-                 proxy_ports is not None),
-                before, after, time.monotonic() - t0)
-        return ring, row_map
+        per_step = (eps, dirns) if packed else ()
+        hdr, valid, now, bid0, *per_step = self._stage(
+            batch_id0, hdr, valid, (now, batch_id0) + per_step)
+        # hdr.shape is (K, bucket, cols): K rides the shape, so the
+        # one-executable invariant keys on (rung, mode, K)
+        return self._dispatch(
+            "super-packed" if packed else "super-wide", batch_id0,
+            serve_superbatch_packed_jit if packed
+            else serve_superbatch_jit,
+            (ring, hdr, now, bid0, *per_step),
+            dict(trace_sample=trace_sample, valid=valid,
+                 proxy_ports=proxy_ports, audit=audit),
+            (int(trace_sample), bool(audit), proxy_ports is not None))
 
     # -- multi-chip serving (parallel/mesh.py) ------------------------
     def serving_shard(self, mesh) -> None:
@@ -940,51 +937,36 @@ class TPULoader(Loader):
         # mesh surfaces as the sharded dispatch raising — exactly
         # where the degraded-mode ladder catches it
         faults.check(faults.SITE_LOADER_SERVE_SHARDED)
-        jnp = self._jnp
         mesh = self._serving_mesh
         assert mesh is not None, "serving_shard(mesh) first"
         packed = packed_meta is not None
         # explicit per-chip placement of the batch OUTSIDE the lock:
         # the h2d copy lands each shard's block on its own chip.
         # P("data") spelling matters for the compile cache — see
-        # parallel.mesh.make_sharded_ring
+        # parallel.mesh.make_sharded_ring; valid reuses row_sh, since
+        # sharding-spelling identity is load-bearing there too
         row_sh = NamedSharding(mesh, P("data"))
-        if isinstance(hdr, np.ndarray):
-            hdr = jax.device_put(np.ascontiguousarray(hdr), row_sh)
-        if isinstance(valid, np.ndarray):
-            # reuse row_sh: sharding-spelling identity is load-bearing
-            # for the compile cache (see make_sharded_ring)
-            valid = jax.device_put(valid, row_sh)
+        hdr, valid, now, bid, *meta = self._stage(
+            batch_id, hdr, valid, (now, batch_id) + tuple(packed_meta or ()),
+            put=lambda x: jax.device_put(x, row_sh))
         if proxy_ports is None:
-            proxy_ports = jnp.zeros((0,), jnp.uint32)
-        now, batch_id = jnp.uint32(now), jnp.uint32(batch_id)
+            proxy_ports = self._jnp.zeros((0,), self._jnp.uint32)
         key = (packed, int(trace_sample), bool(audit))
-        mode = "sharded-packed" if packed else "sharded"
-        with self._lock:
-            step = self._sharded_steps.get(key)
-            if step is None:
-                step = make_sharded_serve_step(
+
+        def step(state, *args):
+            # runs under the lock, which serving_shard() takes to
+            # reset the per-mesh step cache
+            fn = self._sharded_steps.get(key)
+            if fn is None:
+                fn = self._sharded_steps[key] = make_sharded_serve_step(
                     mesh, packed=packed, trace_sample=trace_sample,
                     audit=audit)
-                self._sharded_steps[key] = step
-            before = self._serving_cache_size(mode)
-            t0 = time.monotonic()
-            if packed:
-                ep, dirn = packed_meta
-                self.state, ring = step(
-                    self.state, ring, hdr, now, batch_id, valid,
-                    proxy_ports, jnp.uint32(ep), jnp.uint32(dirn))
-            else:
-                self.state, ring = step(self.state, ring, hdr, now,
-                                        batch_id, valid, proxy_ports)
-            after = self._serving_cache_size(mode)
-            row_map = self.row_map
-        if after > before:
-            self._record_compile(
-                mode, hdr.shape, ring.buf.shape[0],
-                key + (valid is not None,),
-                before, after, time.monotonic() - t0)
-        return ring, row_map
+            return fn(state, *args)
+
+        return self._dispatch(
+            "sharded-packed" if packed else "sharded", batch_id, step,
+            (ring, hdr, now, bid, valid, proxy_ports, *meta), {},
+            key + (valid is not None,))
 
     def add_route_overflow(self, n: int) -> None:
         # thread-affinity: any

@@ -216,17 +216,24 @@ def datapath_step(state: DatapathState, hdr: jnp.ndarray,
     drop: upstream's LB lookup (bpf/lib/lb.h, bpf_sock) runs before
     the endpoint program ever judges the packet, so these rows report
     ``REASON_NO_SERVICE`` regardless of what policy (or the lxcmap
-    gate) would have said, and touch no CT state."""
+    gate) would have said, and touch no CT state.
+
+    Each stage runs under its ``jax.named_scope``
+    (``obs.trace.DEVICE_STAGES``): op metadata only, the same program."""
+    from ..obs.trace import (SCOPE_CT_LOOKUP, SCOPE_CT_UPDATE,
+                             SCOPE_IPCACHE, SCOPE_METRICSMAP, SCOPE_POLICY)
+
     hdr = hdr.astype(jnp.uint32)
     dirn = hdr[:, COL_DIR].astype(jnp.int32)
     fam = hdr[:, COL_FAMILY].astype(jnp.int32)
 
     # 1. ipcache: remote IP -> identity row (src for ingress, dst for
     #    egress — reference: lookup_ip4_remote_endpoint on the peer).
-    src_words = hdr[:, COL_SRC_IP0:COL_SRC_IP0 + 4]
-    dst_words = hdr[:, COL_DST_IP0:COL_DST_IP0 + 4]
-    remote = jnp.where((dirn == 0)[:, None], src_words, dst_words)
-    id_row = lpm_lookup(state.ipcache, remote, fam)
+    with jax.named_scope(SCOPE_IPCACHE):
+        src_words = hdr[:, COL_SRC_IP0:COL_SRC_IP0 + 4]
+        dst_words = hdr[:, COL_DST_IP0:COL_DST_IP0 + 4]
+        remote = jnp.where((dirn == 0)[:, None], src_words, dst_words)
+        id_row = lpm_lookup(state.ipcache, remote, fam)
 
     # 2. conntrack lookup.  RELATED rows (ICMP errors carrying the
     #    embedded original tuple, core/packets.py FLAG_RELATED) probe
@@ -234,128 +241,135 @@ def datapath_step(state: DatapathState, hdr: jnp.ndarray,
     #    like established traffic, never refreshed, never created.
     from ..core.packets import COL_FLAGS, FLAG_RELATED
 
-    fwd, rev = ct_keys_from_headers(hdr)
-    ct_res, slot, is_reply = ct_lookup(state.ct, fwd, rev, now)
-    related_hint = (hdr[:, COL_FLAGS] & FLAG_RELATED) != 0
-    is_related = related_hint & (ct_res != CT_NEW)
+    with jax.named_scope(SCOPE_CT_LOOKUP):
+        fwd, rev = ct_keys_from_headers(hdr)
+        ct_res, slot, is_reply = ct_lookup(state.ct, fwd, rev, now)
+        related_hint = (hdr[:, COL_FLAGS] & FLAG_RELATED) != 0
+        is_related = related_hint & (ct_res != CT_NEW)
 
     # 3. policy map lookup (two gathers; all precedence precompiled).
     #    ep_policy row -1 = unregistered endpoint (the lxcmap-miss
     #    sentinel): the reference DROPS when the endpoint lookup fails
     #    (bpf_lxc lxcmap miss) instead of judging under some other
     #    endpoint's policy.
-    ep_col = hdr[:, COL_EP]  # uint32: range-check BEFORE the int32
-    pol_row_raw = state.policy.ep_policy[ep_col.astype(jnp.int32)]
-    # out-of-range ids would clamp onto the boundary rows in the
-    # gather (>= 4096 -> 4095; >= 2^31 -> wraps negative -> 0) and be
-    # judged under whatever endpoint lives there — a forged ep id must
-    # be a miss, not a clamp
-    no_ep = (pol_row_raw < 0) | (ep_col >= MAX_ENDPOINTS)
-    pol_row = jnp.maximum(pol_row_raw, 0)
-    proto_idx = state.policy.proto_table[hdr[:, COL_PROTO].astype(jnp.int32)]
-    gcls = state.policy.port_class[proto_idx, hdr[:, COL_DPORT].astype(jnp.int32)]
-    # global -> per-policy local class (compiler class_map): the
-    # verdict tensor's class axis is sized to ONE policy's boundaries,
-    # not the union of every policy's (the 17 GB failure mode)
-    cls = state.policy.class_map[pol_row, gcls]
-    packed = state.policy.verdict[pol_row, dirn, id_row, cls]
-    p_verdict = (packed & VERDICT_MASK).astype(jnp.int32)
-    p_proxy = ((packed >> PROXY_SHIFT) & PROXY_MASK).astype(jnp.int32)
-    p_auth = ((packed >> AUTH_SHIFT) & 1) != 0
+    with jax.named_scope(SCOPE_POLICY):
+        ep_col = hdr[:, COL_EP]  # uint32: range-check BEFORE the int32
+        pol_row_raw = state.policy.ep_policy[ep_col.astype(jnp.int32)]
+        # out-of-range ids would clamp onto the boundary rows in the
+        # gather (>= 4096 -> 4095; >= 2^31 -> wraps negative -> 0) and be
+        # judged under whatever endpoint lives there — a forged ep id must
+        # be a miss, not a clamp
+        no_ep = (pol_row_raw < 0) | (ep_col >= MAX_ENDPOINTS)
+        pol_row = jnp.maximum(pol_row_raw, 0)
+        proto_idx = state.policy.proto_table[
+            hdr[:, COL_PROTO].astype(jnp.int32)]
+        gcls = state.policy.port_class[
+            proto_idx, hdr[:, COL_DPORT].astype(jnp.int32)]
+        # global -> per-policy local class (compiler class_map): the
+        # verdict tensor's class axis is sized to ONE policy's boundaries,
+        # not the union of every policy's (the 17 GB failure mode)
+        cls = state.policy.class_map[pol_row, gcls]
+        packed = state.policy.verdict[pol_row, dirn, id_row, cls]
+        p_verdict = (packed & VERDICT_MASK).astype(jnp.int32)
+        p_proxy = ((packed >> PROXY_SHIFT) & PROXY_MASK).astype(jnp.int32)
+        p_auth = ((packed >> AUTH_SHIFT) & 1) != 0
 
-    # 4. final verdict: established/reply bypass policy (reference: the
-    #    CT fast path — policy applies to NEW connections only).
-    is_new = ct_res == CT_NEW
-    ct_proxy = state.ct.table[slot, V_PROXY].astype(jnp.int32)
-    allowed_new = (p_verdict == VERDICT_ALLOW) | (p_verdict == VERDICT_REDIRECT)
-    # no_ep drops even ESTABLISHED traffic: the endpoint is gone/never
-    # existed, so its CT fast path must not forward either
-    allowed = (~is_new | allowed_new) & ~no_ep
-    # mutual auth (pkg/auth): a NEW flow whose winning allow carries
-    # the auth bit forwards only with a live authmap entry; otherwise
-    # it drops AUTH_REQUIRED (and the agent's auth manager observes
-    # the drop and handshakes).  EST flows ride the CT fast path —
-    # upstream judges auth at policy time only.
-    auth_exp = state.policy.auth[pol_row, id_row]
-    auth_drop = allowed & is_new & p_auth & (auth_exp <= now)
-    allowed = allowed & ~auth_drop
-    audit_fwd = None
-    if audit:
-        # policy-audit-mode: would-be policy/auth denials forward
-        audit_fwd = is_new & ~allowed & ~no_ep
-        allowed = allowed | audit_fwd
-    nat_drop = None
-    if pre_drop is not None:
-        nat_drop = pre_drop & allowed  # policy/no_ep drops win
-        allowed = allowed & ~nat_drop
-    stage_drop = None
-    if pre_drop_reason is not None:
-        stage_drop = (pre_drop_reason != 0) & allowed
-        allowed = allowed & ~stage_drop
-    proxy = jnp.where(is_new, jnp.where(p_verdict == VERDICT_REDIRECT,
-                                        p_proxy, 0),
-                      ct_proxy)
-    # an ICMP error related to a proxied flow is forwarded, not
-    # redirected (the proxy speaks the flow's L7, not ICMP)
-    proxy = jnp.where(is_related, 0, proxy)
-    verdict = jnp.where(
-        allowed,
-        jnp.where(proxy > 0, VERDICT_REDIRECT, VERDICT_ALLOW),
-        jnp.where(no_ep, VERDICT_DENY, p_verdict))
-    reason_allowed = (allowed if audit_fwd is None
-                      else allowed & ~audit_fwd)
-    reason = jnp.where(
-        reason_allowed, REASON_FORWARDED,
-        jnp.where(no_ep, REASON_NO_ENDPOINT,
-                  jnp.where(p_verdict == VERDICT_DENY, REASON_POLICY_DENY,
-                            REASON_POLICY_DEFAULT_DENY)))
-    # auth_drop rows carry p_verdict == ALLOW, so the base chain
-    # mislabels them — override both verdict and reason
-    verdict = jnp.where(auth_drop, VERDICT_DENY, verdict)
-    reason = jnp.where(auth_drop, REASON_AUTH_REQUIRED, reason)
-    proxy = jnp.where(auth_drop, 0, proxy)
-    if audit_fwd is not None:
-        # the ACTION is forward; the reason above keeps the would-be
-        # decision (rows a later NAT/bandwidth/LB stage drops get
-        # their reason overridden by that stage, as they really drop)
-        verdict = jnp.where(audit_fwd & allowed, VERDICT_ALLOW,
-                            verdict)
-    if nat_drop is not None:
-        verdict = jnp.where(nat_drop, VERDICT_DENY, verdict)
-        reason = jnp.where(nat_drop, REASON_NAT_EXHAUSTED, reason)
-        proxy = jnp.where(nat_drop, 0, proxy)
-    if stage_drop is not None:
-        verdict = jnp.where(stage_drop, VERDICT_DENY, verdict)
-        reason = jnp.where(stage_drop, pre_drop_reason, reason)
-        proxy = jnp.where(stage_drop, 0, proxy)
-    if lb_drop is not None:
-        # pre-policy: wins over policy/no_ep/NAT/bandwidth reasons
-        allowed = allowed & ~lb_drop
-        verdict = jnp.where(lb_drop, VERDICT_DENY, verdict)
-        reason = jnp.where(lb_drop, REASON_NO_SERVICE, reason)
-        proxy = jnp.where(lb_drop, 0, proxy)
+        # 4. final verdict: established/reply bypass policy (reference: the
+        #    CT fast path — policy applies to NEW connections only).
+        is_new = ct_res == CT_NEW
+        ct_proxy = state.ct.table[slot, V_PROXY].astype(jnp.int32)
+        allowed_new = ((p_verdict == VERDICT_ALLOW)
+                       | (p_verdict == VERDICT_REDIRECT))
+        # no_ep drops even ESTABLISHED traffic: the endpoint is gone/never
+        # existed, so its CT fast path must not forward either
+        allowed = (~is_new | allowed_new) & ~no_ep
+        # mutual auth (pkg/auth): a NEW flow whose winning allow carries
+        # the auth bit forwards only with a live authmap entry; otherwise
+        # it drops AUTH_REQUIRED (and the agent's auth manager observes
+        # the drop and handshakes).  EST flows ride the CT fast path —
+        # upstream judges auth at policy time only.
+        auth_exp = state.policy.auth[pol_row, id_row]
+        auth_drop = allowed & is_new & p_auth & (auth_exp <= now)
+        allowed = allowed & ~auth_drop
+        audit_fwd = None
+        if audit:
+            # policy-audit-mode: would-be policy/auth denials forward
+            audit_fwd = is_new & ~allowed & ~no_ep
+            allowed = allowed | audit_fwd
+        nat_drop = None
+        if pre_drop is not None:
+            nat_drop = pre_drop & allowed  # policy/no_ep drops win
+            allowed = allowed & ~nat_drop
+        stage_drop = None
+        if pre_drop_reason is not None:
+            stage_drop = (pre_drop_reason != 0) & allowed
+            allowed = allowed & ~stage_drop
+        proxy = jnp.where(is_new, jnp.where(p_verdict == VERDICT_REDIRECT,
+                                            p_proxy, 0),
+                          ct_proxy)
+        # an ICMP error related to a proxied flow is forwarded, not
+        # redirected (the proxy speaks the flow's L7, not ICMP)
+        proxy = jnp.where(is_related, 0, proxy)
+        verdict = jnp.where(
+            allowed,
+            jnp.where(proxy > 0, VERDICT_REDIRECT, VERDICT_ALLOW),
+            jnp.where(no_ep, VERDICT_DENY, p_verdict))
+        reason_allowed = (allowed if audit_fwd is None
+                          else allowed & ~audit_fwd)
+        reason = jnp.where(
+            reason_allowed, REASON_FORWARDED,
+            jnp.where(no_ep, REASON_NO_ENDPOINT,
+                      jnp.where(p_verdict == VERDICT_DENY, REASON_POLICY_DENY,
+                                REASON_POLICY_DEFAULT_DENY)))
+        # auth_drop rows carry p_verdict == ALLOW, so the base chain
+        # mislabels them — override both verdict and reason
+        verdict = jnp.where(auth_drop, VERDICT_DENY, verdict)
+        reason = jnp.where(auth_drop, REASON_AUTH_REQUIRED, reason)
+        proxy = jnp.where(auth_drop, 0, proxy)
+        if audit_fwd is not None:
+            # the ACTION is forward; the reason above keeps the would-be
+            # decision (rows a later NAT/bandwidth/LB stage drops get
+            # their reason overridden by that stage, as they really drop)
+            verdict = jnp.where(audit_fwd & allowed, VERDICT_ALLOW,
+                                verdict)
+        if nat_drop is not None:
+            verdict = jnp.where(nat_drop, VERDICT_DENY, verdict)
+            reason = jnp.where(nat_drop, REASON_NAT_EXHAUSTED, reason)
+            proxy = jnp.where(nat_drop, 0, proxy)
+        if stage_drop is not None:
+            verdict = jnp.where(stage_drop, VERDICT_DENY, verdict)
+            reason = jnp.where(stage_drop, pre_drop_reason, reason)
+            proxy = jnp.where(stage_drop, 0, proxy)
+        if lb_drop is not None:
+            # pre-policy: wins over policy/no_ep/NAT/bandwidth reasons
+            allowed = allowed & ~lb_drop
+            verdict = jnp.where(lb_drop, VERDICT_DENY, verdict)
+            reason = jnp.where(lb_drop, REASON_NO_SERVICE, reason)
+            proxy = jnp.where(lb_drop, 0, proxy)
 
     # 5. conntrack create/refresh (create only on allowed NEW; related
     #    rows neither create nor refresh — the ICMP error is evidence
     #    about a flow, not flow traffic; no_ep rows touch nothing).
-    untouched = is_related | no_ep
-    if nat_drop is not None:
-        untouched = untouched | nat_drop  # dropped rows refresh nothing
-    if stage_drop is not None:
-        untouched = untouched | stage_drop
-    if lb_drop is not None:
-        untouched = untouched | lb_drop
-    ct = ct_update(state.ct, hdr, fwd,
-                   jnp.where(untouched, CT_NEW, ct_res), slot,
-                   is_reply,
-                   do_create=allowed & is_new & ~related_hint,
-                   proxy_port=proxy.astype(jnp.uint32),
-                   now=now, valid=valid)
+    with jax.named_scope(SCOPE_CT_UPDATE):
+        untouched = is_related | no_ep
+        if nat_drop is not None:
+            untouched = untouched | nat_drop  # dropped rows refresh nothing
+        if stage_drop is not None:
+            untouched = untouched | stage_drop
+        if lb_drop is not None:
+            untouched = untouched | lb_drop
+        ct = ct_update(state.ct, hdr, fwd,
+                       jnp.where(untouched, CT_NEW, ct_res), slot,
+                       is_reply,
+                       do_create=allowed & is_new & ~related_hint,
+                       proxy_port=proxy.astype(jnp.uint32),
+                       now=now, valid=valid)
 
     # 6. metrics (reference: bpf metricsmap per-reason counters).
-    m_reason = reason if valid is None else jnp.where(valid, reason,
-                                                     N_REASONS)
-    metrics = state.metrics.at[m_reason, dirn].add(1, mode="drop")
+    with jax.named_scope(SCOPE_METRICSMAP):
+        m_reason = reason if valid is None else jnp.where(valid, reason,
+                                                         N_REASONS)
+        metrics = state.metrics.at[m_reason, dirn].add(1, mode="drop")
 
     event = jnp.where(~allowed, EV_DROP,
                       jnp.where(is_new, EV_VERDICT, EV_TRACE))
@@ -415,9 +429,11 @@ def datapath_step_packed(state: DatapathState, packed: jnp.ndarray,
     same fused pipeline.  ``ep``/``dirn`` are per-stream scalars, like
     the per-endpoint tc hook in the reference."""
     from ..core.packets import unpack_hdr
+    from ..obs.trace import SCOPE_UNPACK
 
-    return datapath_step(state, unpack_hdr(packed, ep, dirn), now,
-                         valid=valid, audit=audit)
+    with jax.named_scope(SCOPE_UNPACK):
+        hdr = unpack_hdr(packed, ep, dirn)
+    return datapath_step(state, hdr, now, valid=valid, audit=audit)
 
 
 datapath_step_packed_jit = jax.jit(datapath_step_packed, donate_argnums=0,

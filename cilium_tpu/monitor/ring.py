@@ -110,57 +110,60 @@ def ring_append(ring: EventRing, out: jnp.ndarray, batch_id: jnp.ndarray,
     uint32, 0-padded): redirect events store the PORT's index in it
     (4 bits on the wire); pass the same table to :func:`ring_drain`
     to restore ports.  Without it redirect events decode with proxy
-    port 0.
+    port 0.  Runs under the ``ring`` device-stage scope.
     """
-    n = out.shape[0]
-    idx = jnp.arange(n, dtype=jnp.uint32)
-    keep = out[:, OUT_EVENT] != EV_TRACE
-    if trace_sample:
-        keep = keep | (idx % trace_sample == 0)
-    if valid is not None:
-        keep = keep & valid
-    assert n <= (1 << 19), "pkt_idx packs into 19 bits"
-    pos = jnp.cumsum(keep) - 1  # position among kept rows
-    count = keep.sum().astype(jnp.uint32)
-    mask = ring.capacity - 1
-    lo, hi = ring.cursor[0], ring.cursor[1]
-    slot = ((lo + pos.astype(jnp.uint32)) & mask).astype(jnp.int32)
-    # newest-wins under overflow: when one batch keeps more events than
-    # the ring holds, only the newest `capacity` rows write — otherwise
-    # duplicate slot indices in one scatter would make the survivor
-    # order unspecified
-    newest = pos.astype(jnp.uint32) + ring.capacity >= count
-    target = jnp.where(keep & newest, slot, ring.capacity)  # OOB dropped
-    o = out.astype(jnp.uint32)
-    from ..datapath.verdict import (OUT_CT, OUT_ID_ROW, OUT_PROXY,
-                                    OUT_REASON, OUT_VERDICT)
+    from ..obs.trace import SCOPE_RING
 
-    if proxy_ports is None or proxy_ports.shape[0] == 0:
-        # an EMPTY table also means "no listeners" — the sharded step
-        # passes a zero-length placeholder because shard_map wants a
-        # fixed arity (argmax over a 0-wide axis would be an error)
-        pidx = jnp.zeros(n, dtype=jnp.uint32)
-    else:
-        assert proxy_ports.shape[0] <= MAX_PROXY_PORTS, \
-            "listener index packs into 4 bits"
-        port = o[:, OUT_PROXY]
-        hit = port[:, None] == proxy_ports[None, :].astype(jnp.uint32)
-        pidx = jnp.where(
-            jnp.any(hit, axis=1) & (port != 0),
-            jnp.argmax(hit, axis=1).astype(jnp.uint32) + 1,
-            jnp.uint32(0))
-    # mask each field to its wire width: a value past its width must
-    # corrupt only itself, never a neighbor (the empty-slot sentinel
-    # lives in the event bits)
-    w0 = ((o[:, OUT_VERDICT] & 0x7) | ((o[:, OUT_EVENT] & 0x3) << 3)
-          | ((o[:, OUT_REASON] & 0xF) << 5) | ((o[:, OUT_CT] & 0x7) << 9)
-          | (pidx << 12) | ((o[:, OUT_ID_ROW] & 0xFFFF) << 16))
-    w1 = idx | ((jnp.uint32(batch_id) & jnp.uint32(0x1FFF)) << 19)
-    rows = jnp.stack([w0, w1], axis=1)
-    buf = ring.buf.at[target].set(rows, mode="drop")
-    new_lo = lo + count
-    new_hi = hi + (new_lo < lo).astype(jnp.uint32)  # carry
-    return EventRing(buf=buf, cursor=jnp.stack([new_lo, new_hi]))
+    with jax.named_scope(SCOPE_RING):
+        n = out.shape[0]
+        idx = jnp.arange(n, dtype=jnp.uint32)
+        keep = out[:, OUT_EVENT] != EV_TRACE
+        if trace_sample:
+            keep = keep | (idx % trace_sample == 0)
+        if valid is not None:
+            keep = keep & valid
+        assert n <= (1 << 19), "pkt_idx packs into 19 bits"
+        pos = jnp.cumsum(keep) - 1  # position among kept rows
+        count = keep.sum().astype(jnp.uint32)
+        mask = ring.capacity - 1
+        lo, hi = ring.cursor[0], ring.cursor[1]
+        slot = ((lo + pos.astype(jnp.uint32)) & mask).astype(jnp.int32)
+        # newest-wins under overflow: when one batch keeps more events than
+        # the ring holds, only the newest `capacity` rows write — otherwise
+        # duplicate slot indices in one scatter would make the survivor
+        # order unspecified
+        newest = pos.astype(jnp.uint32) + ring.capacity >= count
+        target = jnp.where(keep & newest, slot, ring.capacity)  # OOB dropped
+        o = out.astype(jnp.uint32)
+        from ..datapath.verdict import (OUT_CT, OUT_ID_ROW, OUT_PROXY,
+                                        OUT_REASON, OUT_VERDICT)
+
+        if proxy_ports is None or proxy_ports.shape[0] == 0:
+            # an EMPTY table also means "no listeners" — the sharded step
+            # passes a zero-length placeholder because shard_map wants a
+            # fixed arity (argmax over a 0-wide axis would be an error)
+            pidx = jnp.zeros(n, dtype=jnp.uint32)
+        else:
+            assert proxy_ports.shape[0] <= MAX_PROXY_PORTS, \
+                "listener index packs into 4 bits"
+            port = o[:, OUT_PROXY]
+            hit = port[:, None] == proxy_ports[None, :].astype(jnp.uint32)
+            pidx = jnp.where(
+                jnp.any(hit, axis=1) & (port != 0),
+                jnp.argmax(hit, axis=1).astype(jnp.uint32) + 1,
+                jnp.uint32(0))
+        # mask each field to its wire width: a value past its width must
+        # corrupt only itself, never a neighbor (the empty-slot sentinel
+        # lives in the event bits)
+        w0 = ((o[:, OUT_VERDICT] & 0x7) | ((o[:, OUT_EVENT] & 0x3) << 3)
+              | ((o[:, OUT_REASON] & 0xF) << 5) | ((o[:, OUT_CT] & 0x7) << 9)
+              | (pidx << 12) | ((o[:, OUT_ID_ROW] & 0xFFFF) << 16))
+        w1 = idx | ((jnp.uint32(batch_id) & jnp.uint32(0x1FFF)) << 19)
+        rows = jnp.stack([w0, w1], axis=1)
+        buf = ring.buf.at[target].set(rows, mode="drop")
+        new_lo = lo + count
+        new_hi = hi + (new_lo < lo).astype(jnp.uint32)  # carry
+        return EventRing(buf=buf, cursor=jnp.stack([new_lo, new_hi]))
 
 
 ring_append_jit = jax.jit(ring_append, donate_argnums=0,

@@ -43,13 +43,28 @@ the breakdown survives ring wrap.  Spans that die mid-pipeline
 (shed by drop-oldest, swept by recovery, lost to a dead dispatch)
 are counted, never silently vanished — the no-silent-loss contract
 the serving ledger has, applied to its own instrumentation.
+
+The same boundaries on the profiler's clock, for a ``jax.profiler``
+trace (``GET /debug/profile``, ``--profile-dir``):
+
+- ``DEVICE_STAGES``: the fused step's stages run under
+  ``jax.named_scope`` of these names, so each device op's ``op_name``
+  metadata names its stage (a fusion carries its root op's scope);
+- ``PROFILE_SPANS``: per-BATCH host spans (``TraceAnnotation``, never
+  per row) on the drain thread and the event-join worker, each with
+  the batch's ring id (``batch=``, the ``batch-id`` of a packet span)
+  as the shared identifier, and the per-packet interval it lies in.
+  :func:`profile_span` costs one enabled check when no trace is on.
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from ..serving.stats import LatencyHistogram
 
@@ -59,6 +74,41 @@ N_STAGES = len(SPAN_STAGES)
 # indices into TraceSpan.ts
 STAGE_ADMIT, STAGE_DEQUEUE, STAGE_STAGED, STAGE_DISPATCH, \
     STAGE_DISPATCH_RET, STAGE_DEVICE, STAGE_JOIN = range(N_STAGES)
+
+# jax.named_scope names of the fused step (datapath/verdict.py,
+# monitor/ring.py), in pipeline order
+DEVICE_STAGES = ("unpack", "ipcache", "ct_lookup", "policy",
+                 "ct_update", "metricsmap", "ring")
+SCOPE_UNPACK, SCOPE_IPCACHE, SCOPE_CT_LOOKUP, SCOPE_POLICY, \
+    SCOPE_CT_UPDATE, SCOPE_METRICSMAP, SCOPE_RING = DEVICE_STAGES
+
+# profiler span -> (thread, the per-packet interval it lies in;
+# None where no packet waits on it)
+PROFILE_SPANS = {
+    "assemble": ("drain", "dequeue->staged"),  # batcher.assemble*
+    "idle": ("drain", None),  # wait_nonempty, the deadline sleep
+    "drain_tick": ("drain", "dispatch->dispatch-ret"),  # cursor sync
+    "h2d": ("drain", "dispatch->dispatch-ret"),  # hdr/valid/scalars
+    "lock_wait": ("drain", "dispatch->dispatch-ret"),  # loader lock
+    "dispatch": ("drain", "dispatch->dispatch-ret"),  # the jit call
+    "fetch": ("event-worker", "dispatch-ret->device"),  # d2h wait
+    "emit": ("event-worker", "device->join"),  # decode + fan-out
+    "analytics": ("event-worker", None),  # flow analytics drain
+}
+
+_NO_SPAN = contextlib.nullcontext()
+_trace_enabled = TraceAnnotation.is_enabled
+_annotation = TraceAnnotation
+
+
+def profile_span(name: str, batch: int):
+    # thread-affinity: any
+    """The ``PROFILE_SPANS`` span ``name`` of batch ``batch`` as a
+    context manager: a profiler ``TraceAnnotation`` while a trace is
+    active, else a shared no-op (no kwargs, no string built)."""
+    if not _trace_enabled():
+        return _NO_SPAN
+    return _annotation(name, batch=batch)
 
 DEFAULT_SPAN_RING = 512
 

@@ -89,6 +89,9 @@ _TICK_S = 0.001
 # in a 50ms wait cannot notice stop/generation churn any faster.
 DEFAULT_IDLE_WAIT_S = 0.05
 _BACKOFF_CAP_S = 1.0
+# the ring's batch-id field width (monitor/ring.py): serve_batch wraps
+# the serving seq by it, and so does the profiler spans' batch id
+_BATCH_ID_MASK = 0x1FFF
 
 
 class ServingRuntime:
@@ -237,6 +240,10 @@ class ServingRuntime:
         self._profile_batches = int(profile_batches)
         self._profile_state = "armed" if profile_dir else "off"
         self._profile_count = 0
+        # batch id the next batch's profiler spans carry (obs/trace.py
+        # PROFILE_SPANS): one past the id the last dispatch returned,
+        # so assembly is tagged before the device leg assigns it
+        self._span_bid = 0
 
     # -- producer side (any thread) -----------------------------------
     def submit(self, rows: np.ndarray,
@@ -469,15 +476,17 @@ class ServingRuntime:
 
     def _loop_body(self, gen: int) -> None:
         # thread-affinity: drain
+        from ..obs.trace import profile_span
         from .batcher import SuperBatch
 
         while not self._stop.is_set() and self._gen_is(gen):
             k_max = self.superbatch_k
-            if k_max > 1 and self._dispatch_super is not None:
-                batch = self.batcher.assemble_super(self.queue,
-                                                    k_max)
-            else:
-                batch = self.batcher.assemble(self.queue)
+            with profile_span("assemble", self._span_bid):
+                if k_max > 1 and self._dispatch_super is not None:
+                    batch = self.batcher.assemble_super(self.queue,
+                                                        k_max)
+                else:
+                    batch = self.batcher.assemble(self.queue)
             if batch is not None:
                 if isinstance(batch, SuperBatch):
                     self._dispatch_one_super(batch, gen)
@@ -507,11 +516,12 @@ class ServingRuntime:
                 # every deadline flush.
                 ttd = self.batcher.time_to_deadline(self.queue)
                 if ttd > 0.0:
-                    # hot-path-ok: the bounded idle tick — rows are
-                    # waiting but neither full-bucket nor deadline
-                    # fired; sleeping toward the deadline IS the
-                    # batching policy, capped at _TICK_S
-                    time.sleep(min(ttd, _TICK_S))
+                    with profile_span("idle", self._span_bid):
+                        # hot-path-ok: the bounded idle tick — rows
+                        # are waiting but neither full-bucket nor
+                        # deadline fired; sleeping toward the deadline
+                        # IS the batching policy, capped at _TICK_S
+                        time.sleep(min(ttd, _TICK_S))
             else:
                 # the idle tick: the registry-backed gauges (queue
                 # depth, arena occupancy, in-flight window) sample
@@ -522,7 +532,8 @@ class ServingRuntime:
                         self._idle_fn()
                     except Exception:  # noqa: BLE001 — an idle hook
                         pass  # must never kill the drain loop
-                self.queue.wait_nonempty(self._idle_wait_s)
+                with profile_span("idle", self._span_bid):
+                    self.queue.wait_nonempty(self._idle_wait_s)
 
     def _dispatch_one(self, batch: AssembledBatch, gen: int) -> None:
         # thread-affinity: drain, api -- stop()'s final drain runs here
@@ -605,6 +616,8 @@ class ServingRuntime:
                 packed = "packed" in mode
             demoted = bool(info.get("demoted"))
             bid = int(info.get("batch_id", -1))
+        self._span_bid = ((bid if bid >= 0 else self._span_bid) + 1) \
+            & _BATCH_ID_MASK
         spans = batch.spans
         if spans:
             from ..obs.trace import STAGE_DISPATCH_RET
@@ -724,6 +737,8 @@ class ServingRuntime:
             # a demoted retry ran K single dispatches, not one fused
             # one — the dispatch scoreboard must count what happened
             n_disp = int(info.get("dispatches", 1))
+        self._span_bid = ((bids[-1] + 1) if bids
+                          else self._span_bid + sb.k) & _BATCH_ID_MASK
         if flat_spans:
             from ..obs.trace import STAGE_DISPATCH_RET
 

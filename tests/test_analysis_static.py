@@ -898,9 +898,10 @@ class TestLiveRepo:
         marker = ('window, s["ring"] = s["drainer"].swap_window('
                   's["ring"], on_cold)')
         assert marker in src
-        src = src.replace(marker, marker + """
-        from ..monitor.api import decode_ring_rows
-        decode_ring_rows(None, None, None, 0.0)""")
+        indent = src[:src.index(marker)].rsplit("\n", 1)[1]
+        src = src.replace(marker, marker + f"""
+{indent}from ..monitor.api import decode_ring_rows
+{indent}decode_ring_rows(None, None, None, 0.0)""")
         daemon.write_text(src)
         result = run_analysis(root=str(tmp_path))
         assert any(f.code == "CTA002"

@@ -21,6 +21,7 @@ Covers the PR-4 tentpole acceptance properties:
 
 from __future__ import annotations
 
+import threading
 import time
 
 import numpy as np
@@ -29,7 +30,8 @@ import pytest
 from cilium_tpu.agent import Daemon, DaemonConfig
 from cilium_tpu.core import TCP_SYN, make_batch
 from cilium_tpu.obs import SpanTracer
-from cilium_tpu.obs.trace import SPAN_STAGES, validate_obs_config
+from cilium_tpu.obs.trace import (PROFILE_SPANS, SPAN_STAGES,
+                                  validate_obs_config)
 from cilium_tpu.serving import DispatchFailedError, ServingRuntime
 
 pytestmark = pytest.mark.obs
@@ -421,3 +423,124 @@ class TestAssemblyFailureEviction:
         assert st["started"] == 8
         assert st["dropped"] == 8, st
         assert q.pop_dequeued_spans() == []  # nothing orphaned
+
+
+class _SpanLog:
+    """Stands in for ``jax.profiler.TraceAnnotation`` while a trace is
+    "active": records (name, batch id, thread) of every span opened."""
+
+    def __init__(self, monkeypatch):
+        from cilium_tpu.obs import trace
+
+        self.spans = []
+        log = self.spans
+
+        class Recorded:
+            def __init__(self, name, batch):
+                self.entry = (name, batch)
+
+            def __enter__(self):
+                log.append(self.entry + (threading.get_ident(),))
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(trace, "_trace_enabled", lambda: True)
+        monkeypatch.setattr(trace, "_annotation", Recorded)
+
+    def ids(self, name):
+        return [b for n, b, _ in self.spans if n == name]
+
+
+class TestProfileSpans:
+    """Per-batch profiler spans (obs.trace.PROFILE_SPANS): one set per
+    batch under the batch's ring id, never one per row, and no
+    annotation built while no trace is active."""
+
+    def test_off_returns_shared_noop_without_building_a_span(
+            self, monkeypatch):
+        from cilium_tpu.obs import trace
+
+        def boom(*a, **kw):
+            raise AssertionError("annotation built with no trace on")
+
+        monkeypatch.setattr(trace, "_annotation", boom)
+        assert not trace._trace_enabled()
+        assert trace.profile_span("h2d", 3) is trace._NO_SPAN
+        with trace.profile_span("dispatch", 3):
+            pass
+
+    def test_each_batch_spans_share_its_batch_id(self, monkeypatch):
+        log = _SpanLog(monkeypatch)
+        d = Daemon(DaemonConfig(
+            backend="tpu", ct_capacity=1 << 12,
+            flow_ring_capacity=1 << 13,
+            serving_queue_depth=4096,
+            serving_bucket_ladder=(64,),
+            serving_max_wait_us=500.0,
+            # room for every window, so none is dropped while the
+            # worker's first gather compiles
+            serving_window_queue_depth=16))
+        d.add_endpoint("web", ("10.0.1.1",), ["k8s:app=web"])
+        db = d.add_endpoint("db", ("10.0.2.1",), ["k8s:app=db"])
+        d.policy_import(RULES)
+        d.start_serving(trace_sample=0, ingress=True, drain_every=2)
+        rt = d._serving["runtime"]
+        # eight full buckets in one chunk: back-to-back dispatches
+        d.submit(np.concatenate([_fwd(db.id, base=30000 + 64 * i)
+                                 for i in range(8)]))
+        assert _wait(lambda: rt.stats.verdicts >= 8 * 64)
+        d.stop_serving()
+        d.shutdown()
+
+        batches = list(range(8))
+        # one h2d / lock_wait / dispatch per batch, not per row
+        for name in ("h2d", "lock_wait", "dispatch"):
+            assert log.ids(name) == batches, name
+        assert set(batches) <= set(log.ids("assemble"))
+        # the drain tick every drain_every batches, before the
+        # dispatch of the batch whose id it carries
+        assert {2, 4, 6} <= set(log.ids("drain_tick"))
+        drain = {t for n, _, t in log.spans if n == "dispatch"}
+        assert len(drain) == 1
+        for n, b, t in log.spans:
+            if n in ("assemble", "h2d", "lock_wait", "dispatch"):
+                assert t in drain, (n, b)
+        # each swapped window is joined on the event-join worker under
+        # the id of the drain tick that swapped it
+        worker = {t for n, _, t in log.spans if n == "fetch"}
+        assert worker and not worker & drain
+        for name in ("fetch", "emit", "analytics"):
+            assert set(log.ids(name)) == set(log.ids("drain_tick")), name
+        assert {n for n, _, _ in log.spans} <= set(PROFILE_SPANS)
+
+    def test_assembly_carries_the_id_the_next_dispatch_returns(
+            self, monkeypatch):
+        """A bare runtime learns the ring's batch ids from what its
+        dispatcher returns, so a batch's assembly is tagged with the id
+        its dispatch then gets (ids wrap at the ring's 13-bit field)."""
+        log = _SpanLog(monkeypatch)
+        seq = iter(range(8190, 8200))
+
+        def dispatch(hdr, valid, n_valid, **kw):
+            bid = next(seq) & 0x1FFF
+            log.spans.append(("dispatched", bid, 0))
+            return {"h2d_bytes": hdr.nbytes, "mode": "wide",
+                    "batch_id": bid}
+
+        rt = ServingRuntime(dispatch, queue_depth=4096,
+                            bucket_ladder=(64,), max_wait_us=300.0,
+                            expected_cols=COLS)
+        rt.start()
+        rt.submit(np.zeros((4 * 64, COLS), dtype=np.uint32))
+        assert _wait(lambda: rt.stats.verdicts >= 4 * 64)
+        rt.stop()
+        tagged = []
+        for i, (n, b, _) in enumerate(log.spans):
+            if n == "dispatched":
+                tagged.append((b, [x for x in log.spans[:i]
+                                   if x[0] == "assemble"][-1][1]))
+        assert [b for b, _ in tagged] == [8190, 8191, 0, 1]
+        # the first assembly precedes any returned id
+        assert all(b == a for b, a in tagged[1:]), tagged
