@@ -92,10 +92,11 @@ class CTTable:
 
     ``fp`` is a per-slot 1-byte key fingerprint (0 = free slot) kept in
     its own HBM array: probes gather the 16-slot fingerprint window
-    first (64 B/packet) and fetch full 68 B rows only for the few
-    fingerprint-matching candidates — a ~3x probe-byte diet over
-    loading the whole [N, 16, ROW_WORDS] window.  The fingerprint is a
-    pure function of the stored key (``_fp_mix`` of the slot hash), so
+    first (two aligned 64 B blocks per key, :func:`_fp_window`) and
+    fetch full 68 B rows only for the few fingerprint-matching
+    candidates — a ~3x probe-byte diet over loading the whole
+    [N, 16, ROW_WORDS] window.  The fingerprint is a pure function of
+    the stored key (``_fp_mix`` of the slot hash), so
     snapshots stay placement-free and restores recompute it."""
 
     table: jnp.ndarray  # [C, ROW_WORDS] uint32
@@ -221,10 +222,8 @@ def _probe(table: jnp.ndarray, keys: jnp.ndarray, now: jnp.ndarray
         raise ValueError(
             f"CT probe needs 2^k capacity, got {c} — a multi-shard "
             "table must be probed inside shard_map (per-shard slice)")
-    mask = table.shape[0] - 1
     h = _hash(keys)
-    steps = jnp.arange(N_PROBE, dtype=jnp.uint32)
-    slots = ((h[:, None] + steps[None, :]) & mask).astype(jnp.int32)
+    slots = _slots(h, jnp.arange(N_PROBE)[None, :], c - 1)
     rows = table[slots]  # [N, N_PROBE, ROW_WORDS] — one gather
     live = (rows[:, :, V_STATE] != ST_FREE) & (rows[:, :, V_EXPIRES]
                                                >= now)
@@ -236,15 +235,45 @@ def _probe(table: jnp.ndarray, keys: jnp.ndarray, now: jnp.ndarray
     return found, jnp.where(found, slot, 0).astype(jnp.int32)
 
 
-def _fp_window(fp: jnp.ndarray, keys: jnp.ndarray
-               ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Gather each key's fingerprint window: -> (slots [N, N_PROBE],
-    window fingerprints [N, N_PROBE], key fingerprint [N])."""
-    mask = fp.shape[0] - 1
-    h = _hash(keys)
-    steps = jnp.arange(N_PROBE, dtype=jnp.uint32)
-    slots = ((h[:, None] + steps[None, :]) & mask).astype(jnp.int32)
-    return slots, fp[slots], _fp_mix(h)
+def _slots(h: jnp.ndarray, pos: jnp.ndarray, mask: int) -> jnp.ndarray:
+    """Window positions -> table slots: ``(h + pos) & mask`` as int32.
+
+    ``h`` is [N] and ``pos`` a position, [N] or [N, k]: plain
+    arithmetic, so a candidate's slot costs no gather."""
+    pos = jnp.asarray(pos).astype(jnp.uint32)
+    if pos.ndim == 2:
+        h = h[:, None]
+    return ((h + pos) & mask).astype(jnp.int32)
+
+
+def _fp_window(fp: jnp.ndarray, h: jnp.ndarray) -> jnp.ndarray:
+    """Each key's fingerprint window: [N] key hashes -> [N, N_PROBE],
+    position j holding ``fp[(h + j) & mask]``.
+
+    On the TPU a gather costs about the same per index whatever the
+    bytes per index, so the window is fetched as whole aligned blocks:
+    ``fp`` viewed as [C / N_PROBE, N_PROBE], the home slot's block and
+    the next one (wrapping at the table's end) in ONE gather of 2 rows
+    per key, then shifted to the home slot by a barrel shift of static
+    rolls, one per bit of ``h & (N_PROBE - 1)``.  A per-key
+    ``dynamic_slice`` would lower to a serial ``while`` on the TPU.  A
+    table smaller than one block gathers per slot."""
+    c = fp.shape[0]
+    mask = c - 1
+    if c < N_PROBE:
+        return fp[_slots(h, jnp.arange(N_PROBE)[None, :], mask)]
+    n_blocks = c // N_PROBE
+    block = (h & mask) // N_PROBE
+    blocks = jnp.stack([block, (block + 1) % n_blocks], axis=1)
+    win = fp.reshape(n_blocks, N_PROBE)[blocks.astype(jnp.int32)]
+    win = win.reshape(-1, 2 * N_PROBE)
+    off = h % N_PROBE
+    bit = N_PROBE // 2
+    while bit:
+        win = jnp.where(((off & bit) != 0)[:, None],
+                        jnp.roll(win, -bit, axis=1), win)
+        bit //= 2
+    return win[:, :N_PROBE]
 
 
 def _first_k(mask: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -261,18 +290,18 @@ def _probe_fp(table: jnp.ndarray, fp: jnp.ndarray, keys: jnp.ndarray,
               ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fingerprint-filtered probe: -> (found, slot, overflow).
 
-    Gathers the 16-slot fingerprint window (16 words/key), then full
-    rows for only the first ``N_CAND`` fingerprint matches.  Exactness:
+    Gathers the 16-slot fingerprint window (:func:`_fp_window`), then
+    full rows for only the first ``N_CAND`` fingerprint matches.  Exactness:
     a miss with more than ``N_CAND`` fingerprint matches in the window
     is flagged ``overflow`` — the true entry could hide past the
     candidate budget (P ~ (occupancy/255)^N_CAND per probe), and the
     caller reruns the full-window probe under ``lax.cond``.  Stale
     fingerprints of expired-but-unswept entries only cost a candidate
     slot; the liveness check on the gathered row rejects them."""
-    slots, win_fp, key_fp = _fp_window(fp, keys)
-    fmatch = win_fp == key_fp[:, None]  # [N, N_PROBE]
+    h = _hash(keys)
+    fmatch = _fp_window(fp, h) == _fp_mix(h)[:, None]  # [N, N_PROBE]
     pos, cand_valid = _first_k(fmatch, N_CAND)
-    cand_slots = jnp.take_along_axis(slots, pos, axis=1)  # [N, N_CAND]
+    cand_slots = _slots(h, pos, fp.shape[0] - 1)  # [N, N_CAND]
     rows = table[cand_slots]  # [N, N_CAND, ROW_WORDS]
     live = (rows[:, :, V_STATE] != ST_FREE) & (rows[:, :, V_EXPIRES]
                                                >= now)
@@ -392,7 +421,10 @@ def ct_update(ct: CTTable, hdr: jnp.ndarray, fwd: jnp.ndarray,
     ], axis=1)  # [N, ROW_WORDS]
 
     fp = ct.fp
-    slots_w, win_fp, key_fp = _fp_window(fp, fwd)
+    mask = capacity - 1
+    h = _hash(fwd)
+    win_fp = _fp_window(fp, h)
+    key_fp = _fp_mix(h)
 
     def _claim(table, fp, pending, s, also_try=None):
         stored = table[s]
@@ -417,8 +449,8 @@ def ct_update(ct: CTTable, hdr: jnp.ndarray, fwd: jnp.ndarray,
     cand_mask = (win_fp == 0) | (win_fp == key_fp[:, None])
     pos, cand_valid = _first_k(cand_mask, N_CAND_INS)
     for k in range(N_CAND_INS):
-        s = jnp.take_along_axis(slots_w, pos[:, k:k + 1], axis=1)[:, 0]
-        table, fp, pending = _claim(table, fp, pending, s,
+        table, fp, pending = _claim(table, fp, pending,
+                                    _slots(h, pos[:, k], mask),
                                     cand_valid[:, k])
 
     # exact fallback: a still-pending insert might claim an
@@ -430,7 +462,7 @@ def ct_update(ct: CTTable, hdr: jnp.ndarray, fwd: jnp.ndarray,
         table, fp, pending = args
         for step in range(N_PROBE):
             table, fp, pending = _claim(table, fp, pending,
-                                        slots_w[:, step])
+                                        _slots(h, step, mask))
         return table, fp, pending
 
     table, fp, pending = jax.lax.cond(

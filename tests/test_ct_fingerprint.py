@@ -10,6 +10,7 @@ ct_create probe loop (SURVEY.md §2a:90).
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from cilium_tpu.core import TCP_ACK, TCP_SYN, make_batch
 from cilium_tpu.datapath import CTTable
@@ -20,15 +21,20 @@ from cilium_tpu.datapath.conntrack import (
     KEY_WORDS,
     LIFETIME_SYN,
     N_CAND,
+    N_CAND_INS,
+    N_PROBE,
     ST_FREE,
     V_EXPIRES,
     V_STATE,
     _fp_mix,
+    _first_k,
     _fp_mix_np,
+    _fp_window,
     _hash,
     _hash_np,
     _probe,
     _probe_fp,
+    _slots,
     ct_fp_from_table,
     ct_gc,
     ct_keys_jit,
@@ -155,37 +161,14 @@ class TestFingerprintProbe:
         """Randomized gate: across batches of inserts, refreshes,
         expiries, and GC sweeps, the fingerprint probe must equal the
         full-window probe on EVERY key, hit or miss."""
-        rng = np.random.default_rng(42)
-        cap = 1 << 10  # small: forces collisions + window pressure
-        ct = CTTable.create(cap)
-        now = 100
-        universe = _flows(600, seed=7)  # ~60% occupancy at peak
-        for step in range(12):
-            pick = rng.choice(600, 128, replace=False)
-            hdr = jnp.asarray(universe.data[pick])
-            fwd, rev = ct_keys_jit(hdr)
-            t = jnp.uint32(now)
-            res, slot, rep = ct_lookup_jit(ct, fwd, rev, t)
-            ct = ct_update_jit(ct, hdr, fwd, res, slot, rep,
-                               do_create=jnp.ones(128, bool),
-                               proxy_port=jnp.zeros(128, jnp.uint32),
-                               now=t)
-            # equivalence sweep over the WHOLE universe
-            afwd, arev = ct_keys_jit(jnp.asarray(universe.data))
-            for keys in (afwd, arev):
-                f0, s0 = _probe(ct.table, keys, t)
-                f1, s1, ovf = _probe_fp(ct.table, ct.fp, keys, t)
-                f1 = np.asarray(f1) | np.asarray(ovf)  # ovf -> full
-                # where no overflow, results must match exactly
-                clean = ~np.asarray(ovf)
-                np.testing.assert_array_equal(np.asarray(f0)[clean],
-                                              np.asarray(f1)[clean])
-                np.testing.assert_array_equal(
-                    np.asarray(s0)[clean & np.asarray(f0)],
-                    np.asarray(s1)[clean & np.asarray(f0)])
-            now += rng.integers(1, 40)  # let lifetimes expire mid-run
-            if step % 4 == 3:
-                ct, _n = ct_gc(ct, jnp.uint32(now))
+        # small: forces collisions + window pressure, ~60% occupancy
+        # at peak
+        _lifecycle_fuzz(cap=1 << 10, n_flows=600, batch=128)
+
+    def test_probe_equivalence_fuzz_wrapping_windows(self):
+        # two windows' worth of slots: half the home slots lie in the
+        # last 15, so their windows wrap past the table's end
+        _lifecycle_fuzz(cap=2 * N_PROBE, n_flows=40, batch=16)
 
     def test_host_fp_mix_mirrors_device(self):
         keys = np.asarray(_seed_table(n=32)[2])
@@ -193,3 +176,86 @@ class TestFingerprintProbe:
         h_np = _fp_mix_np(_hash_np(keys))
         np.testing.assert_array_equal(h_dev, h_np)
         assert h_np.min() >= 1 and h_np.max() <= 255
+
+
+def _lifecycle_fuzz(cap, n_flows, batch):
+    rng = np.random.default_rng(42)
+    ct = CTTable.create(cap)
+    now = 100
+    universe = _flows(n_flows, seed=7)
+    afwd, arev = ct_keys_jit(jnp.asarray(universe.data))
+    for step in range(12):
+        pick = rng.choice(n_flows, batch, replace=False)
+        hdr = jnp.asarray(universe.data[pick])
+        fwd, rev = ct_keys_jit(hdr)
+        t = jnp.uint32(now)
+        res, slot, rep = ct_lookup_jit(ct, fwd, rev, t)
+        ct = ct_update_jit(ct, hdr, fwd, res, slot, rep,
+                           do_create=jnp.ones(batch, bool),
+                           proxy_port=jnp.zeros(batch, jnp.uint32),
+                           now=t)
+        table = np.asarray(ct.table)
+        live = table[:, V_STATE] != ST_FREE
+        np.testing.assert_array_equal(np.asarray(ct.fp)[live],
+                                      ct_fp_from_table(table)[live])
+        # equivalence sweep over the WHOLE universe
+        for keys in (afwd, arev):
+            f0, s0 = _probe(ct.table, keys, t)
+            f1, s1, ovf = _probe_fp(ct.table, ct.fp, keys, t)
+            # where no overflow, results must match exactly
+            clean = ~np.asarray(ovf)
+            np.testing.assert_array_equal(np.asarray(f0)[clean],
+                                          np.asarray(f1)[clean])
+            np.testing.assert_array_equal(
+                np.asarray(s0)[clean & np.asarray(f0)],
+                np.asarray(s1)[clean & np.asarray(f0)])
+        now += rng.integers(1, 40)  # let lifetimes expire mid-run
+        if step % 4 == 3:
+            ct, _n = ct_gc(ct, jnp.uint32(now))
+
+
+def _hashes(cap, n, rng):
+    """``n`` random key hashes, a quarter of them homed in the table's
+    last 15 slots (their windows wrap past the end)."""
+    h = rng.integers(0, 1 << 32, n, dtype=np.uint64).astype(np.uint32)
+    tail = n // 4
+    h[:tail] = ((h[:tail] & np.uint32(~(cap - 1) & 0xFFFFFFFF))
+                | np.uint32(cap - 1)
+                - rng.integers(0, min(15, cap), tail).astype(np.uint32))
+    return h
+
+
+def _window_slots(h, cap):
+    steps = np.arange(N_PROBE, dtype=np.uint64)
+    return ((h[:, None].astype(np.uint64) + steps)
+            & np.uint64(cap - 1)).astype(np.int32)
+
+
+@pytest.mark.parametrize("cap", [8, 16, 32, 1 << 10, 1 << 19])
+def test_block_window_equals_per_slot_window(cap):
+    """The block-gathered window is ``fp[slots]`` bit for bit, at the
+    table's end too; below one block the window gathers per slot."""
+    rng = np.random.default_rng(cap)
+    fp = rng.integers(0, 256, cap, dtype=np.uint32)
+    h = _hashes(cap, 4096, rng)
+    got = np.asarray(_fp_window(jnp.asarray(fp), jnp.asarray(h)))
+    np.testing.assert_array_equal(got, fp[_window_slots(h, cap)])
+
+
+@pytest.mark.parametrize("cap", [16, 32, 1 << 10, 1 << 19])
+def test_candidate_slots_by_arithmetic(cap):
+    """``(h + pos) & mask`` is the slot the window held at ``pos``, for
+    the positions ``_first_k`` picks, as a block and per column."""
+    rng = np.random.default_rng(cap + 1)
+    h = _hashes(cap, 4096, rng)
+    slots = _window_slots(h, cap)
+    cand = rng.random((len(h), N_PROBE)) < 0.3
+    for k in (N_CAND, N_CAND_INS):
+        pos, _valid = _first_k(jnp.asarray(cand), k)
+        want = np.take_along_axis(slots, np.asarray(pos), axis=1)
+        got = _slots(jnp.asarray(h), pos, cap - 1)
+        np.testing.assert_array_equal(np.asarray(got), want)
+        for j in range(k):
+            np.testing.assert_array_equal(
+                np.asarray(_slots(jnp.asarray(h), pos[:, j], cap - 1)),
+                want[:, j])

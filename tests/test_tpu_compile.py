@@ -11,6 +11,8 @@ only one process may load the TPU library, and under pytest-xdist every
 worker imports this file.  Keep these tests in this one file.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -21,6 +23,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 from cilium_tpu.agent.daemon import DaemonConfig
 from cilium_tpu.core.packets import N_COLS, PACKED_COLS
+from cilium_tpu.datapath.conntrack import (KEY_WORDS, N_PROBE, CTTable,
+                                           ct_lookup_jit)
 from cilium_tpu.datapath.verdict import datapath_step_packed_jit
 from cilium_tpu.monitor.ring import EventRing, serve_step_packed_jit
 from cilium_tpu.parallel.mesh import make_sharded_serve_step
@@ -29,6 +33,7 @@ from cilium_tpu.testing.fixtures import build_world
 HBM_BYTES = 16 << 30  # one v5e chip
 TOP_RUNG = DaemonConfig.serving_bucket_ladder[-1]
 RING_CAPACITY = 1 << 15  # Daemon.start_serving's default
+CT_DEFAULT = 1 << 19  # upstream's --bpf-ct-global-tcp-max
 SHARD_HEADROOM = 2  # Daemon.start_serving's default
 
 
@@ -135,3 +140,59 @@ def test_sharded_serve_step_2x2(topo, state_shapes):
     ).compile()
     _check(compiled)
     assert "all-reduce" in compiled.as_text()  # the psum-ed counters
+
+
+def _fast_path_gathers(hlo: str):
+    """(result shape, operand shape) of every gather outside the
+    branches of a ``conditional``: the fast path, with the
+    ``lax.cond`` fallbacks left out."""
+    called = re.compile(r"(?:calls|to_apply|body|condition|"
+                        r"branch_computations)=(\{[^}]*\}|%[\w.-]+)")
+
+    def callees(lines):
+        return [name for line in lines for group in called.findall(line)
+                for name in re.findall(r"%([\w.-]+)", group)]
+
+    comps, shapes, cur = {}, {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) \(", line)
+        if head and line.rstrip().endswith("{"):
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+            m = re.match(r"\s*(?:ROOT )?%([\w.-]+) = (\w+\[[\d,]*\])",
+                         line)
+            if m:
+                shapes[m.group(1)] = m.group(2)
+    todo = callees(line for body in comps.values() for line in body
+                   if " conditional(" in line)
+    slow = set()
+    while todo:
+        name = todo.pop()
+        if name not in slow:
+            slow.add(name)
+            todo += callees(comps.get(name, ()))
+    return [(m.group(1), shapes.get(m.group(2)))
+            for name, body in comps.items() if name not in slow
+            for m in (re.match(r"\s*(?:ROOT )?%[\w.-]+ = (\w+\[[\d,]*\])"
+                               r"\S* gather\(%([\w.-]+)", line)
+                      for line in body) if m]
+
+
+def test_ct_lookup_fingerprint_probe_gathers_blocks(one_chip):
+    """The fingerprint probe gathers each key's window as two aligned
+    N_PROBE-slot blocks of ``fp`` and computes candidate slots: no
+    per-slot gather into ``fp`` (16 x rows indices), no gather from a
+    computed [rows, 16] slot array."""
+    ct = jax.eval_shape(lambda: CTTable.create(CT_DEFAULT))
+    keys = jax.ShapeDtypeStruct((TOP_RUNG, KEY_WORDS), jnp.uint32,
+                                sharding=one_chip)
+    compiled = ct_lookup_jit.lower(_on(ct, one_chip), keys, keys,
+                                   _scalar(one_chip)).compile()
+    _check(compiled)
+    gathers = _fast_path_gathers(compiled.as_text())
+    operands = {op for _res, op in gathers}
+    assert f"u32[{CT_DEFAULT},17]" in operands, gathers  # candidate rows
+    assert f"u32[{CT_DEFAULT // N_PROBE},{N_PROBE}]" in operands, gathers
+    assert f"u32[{CT_DEFAULT}]" not in operands, gathers
+    assert f"s32[{TOP_RUNG},{N_PROBE}]" not in operands, gathers
