@@ -360,6 +360,8 @@ def ct_update(ct: CTTable, hdr: jnp.ndarray, fwd: jnp.ndarray,
     ``valid`` masks out padding rows (batch routing pads shards to a
     common size); invalid rows touch nothing.
     """
+    from ..obs.trace import SCOPE_CT_CLAIM
+
     proto = hdr[:, COL_PROTO].astype(jnp.uint32)
     flags = hdr[:, COL_FLAGS].astype(jnp.uint32)
     length = hdr[:, COL_LEN].astype(jnp.uint32)
@@ -402,71 +404,83 @@ def ct_update(ct: CTTable, hdr: jnp.ndarray, fwd: jnp.ndarray,
     table = table.at[upd_rows, byte_col].add(length, mode="drop")
 
     # --- insert NEW entries (write-then-verify claim) ------------------
+    # Only a batch with a pending insert runs the claim: established
+    # traffic inserts nothing, and the claim's row scatters cost their
+    # indices even for rows sent to the dropped index ``capacity``.
+    # The fingerprint window stays outside the branch: it is the one
+    # ``ct_lookup`` gathers for ``fwd`` from the same ``fp``.
     pending = do_create & (result == CT_NEW)
     if valid is not None:
         pending = pending & valid
-    init_state = jnp.where(is_tcp, ST_SYN_SENT, ST_ESTABLISHED)
-    init_life = jnp.where(is_tcp, LIFETIME_SYN, LIFETIME_NONTCP)
-    new_row = jnp.concatenate([
-        fwd,
-        jnp.stack([
-            init_state.astype(jnp.uint32),
-            now + init_life.astype(jnp.uint32),
-            jnp.ones_like(length),  # tx_pkts
-            jnp.zeros_like(length),
-            length,  # tx_bytes
-            jnp.zeros_like(length),
-            proxy_port.astype(jnp.uint32),
-        ], axis=1),
-    ], axis=1)  # [N, ROW_WORDS]
-
-    fp = ct.fp
-    mask = capacity - 1
     h = _hash(fwd)
-    win_fp = _fp_window(fp, h)
-    key_fp = _fp_mix(h)
+    win_fp = _fp_window(ct.fp, h)
 
-    def _claim(table, fp, pending, s, also_try=None):
-        stored = table[s]
-        claimable = ((stored[:, V_STATE] == ST_FREE)
-                     | (stored[:, V_EXPIRES] < now)
-                     | jnp.all(stored[:, :KEY_WORDS] == fwd, axis=1))
-        trying = pending & claimable
-        if also_try is not None:
-            trying = trying & also_try
-        rows = jnp.where(trying, s, capacity)
-        table = table.at[rows].set(new_row, mode="drop")
-        # gather whole rows, then slice: a partial-row gather
-        # (table[s, :KEY_WORDS]) lowers to a serial loop on the TPU
-        won = trying & jnp.all(table[s][:, :KEY_WORDS] == fwd, axis=1)
-        fp = fp.at[jnp.where(won, s, capacity)].set(key_fp, mode="drop")
-        return table, fp, pending & ~won
-
-    # fast path: claim among fingerprint-filtered candidates only —
-    # free slots (fp 0) and same-fingerprint slots (own key re-claim,
-    # expired twins).  Probe-byte diet: N_CAND_INS row gathers instead
-    # of N_PROBE.
-    cand_mask = (win_fp == 0) | (win_fp == key_fp[:, None])
-    pos, cand_valid = _first_k(cand_mask, N_CAND_INS)
-    for k in range(N_CAND_INS):
-        table, fp, pending = _claim(table, fp, pending,
-                                    _slots(h, pos[:, k], mask),
-                                    cand_valid[:, k])
-
-    # exact fallback: a still-pending insert might claim an
-    # expired-other-key slot the fingerprint can't identify, or lost
-    # every candidate to same-window racers — rerun the full-window
-    # loop for the batch (rare: needs >= N_CAND_INS contenders or an
-    # exhausted window, so steady state never pays it)
-    def _full(args):
+    @jax.named_scope(SCOPE_CT_CLAIM)
+    def _insert(args):
         table, fp, pending = args
-        for step in range(N_PROBE):
+        mask = capacity - 1
+        init_state = jnp.where(is_tcp, ST_SYN_SENT, ST_ESTABLISHED)
+        init_life = jnp.where(is_tcp, LIFETIME_SYN, LIFETIME_NONTCP)
+        new_row = jnp.concatenate([
+            fwd,
+            jnp.stack([
+                init_state.astype(jnp.uint32),
+                now + init_life.astype(jnp.uint32),
+                jnp.ones_like(length),  # tx_pkts
+                jnp.zeros_like(length),
+                length,  # tx_bytes
+                jnp.zeros_like(length),
+                proxy_port.astype(jnp.uint32),
+            ], axis=1),
+        ], axis=1)  # [N, ROW_WORDS]
+        key_fp = _fp_mix(h)
+
+        def _claim(table, fp, pending, s, also_try=None):
+            stored = table[s]
+            claimable = ((stored[:, V_STATE] == ST_FREE)
+                         | (stored[:, V_EXPIRES] < now)
+                         | jnp.all(stored[:, :KEY_WORDS] == fwd, axis=1))
+            trying = pending & claimable
+            if also_try is not None:
+                trying = trying & also_try
+            rows = jnp.where(trying, s, capacity)
+            table = table.at[rows].set(new_row, mode="drop")
+            # gather whole rows, then slice: a partial-row gather
+            # (table[s, :KEY_WORDS]) lowers to a serial loop on the TPU
+            won = trying & jnp.all(table[s][:, :KEY_WORDS] == fwd, axis=1)
+            fp = fp.at[jnp.where(won, s, capacity)].set(key_fp,
+                                                        mode="drop")
+            return table, fp, pending & ~won
+
+        # fast path: claim among fingerprint-filtered candidates only —
+        # free slots (fp 0) and same-fingerprint slots (own key
+        # re-claim, expired twins).  Probe-byte diet: N_CAND_INS row
+        # gathers instead of N_PROBE.
+        cand_mask = (win_fp == 0) | (win_fp == key_fp[:, None])
+        pos, cand_valid = _first_k(cand_mask, N_CAND_INS)
+        for k in range(N_CAND_INS):
             table, fp, pending = _claim(table, fp, pending,
-                                        _slots(h, step, mask))
-        return table, fp, pending
+                                        _slots(h, pos[:, k], mask),
+                                        cand_valid[:, k])
+
+        # exact fallback: a still-pending insert might claim an
+        # expired-other-key slot the fingerprint can't identify, or
+        # lost every candidate to same-window racers — rerun the
+        # full-window loop for the batch (rare: needs >= N_CAND_INS
+        # contenders or an exhausted window, so steady state never
+        # pays it)
+        def _full(args):
+            table, fp, pending = args
+            for step in range(N_PROBE):
+                table, fp, pending = _claim(table, fp, pending,
+                                            _slots(h, step, mask))
+            return table, fp, pending
+
+        return jax.lax.cond(jnp.any(pending), _full, lambda a: a,
+                            (table, fp, pending))
 
     table, fp, pending = jax.lax.cond(
-        jnp.any(pending), _full, lambda a: a, (table, fp, pending))
+        jnp.any(pending), _insert, lambda a: a, (table, ct.fp, pending))
 
     dropped = ct.dropped + jnp.sum(pending).astype(jnp.uint32)
     return CTTable(table=table, fp=fp, dropped=dropped)

@@ -81,6 +81,11 @@ DEVICE_STAGES = ("unpack", "ipcache", "ct_lookup", "policy",
                  "ct_update", "metricsmap", "ring")
 SCOPE_UNPACK, SCOPE_IPCACHE, SCOPE_CT_LOOKUP, SCOPE_POLICY, \
     SCOPE_CT_UPDATE, SCOPE_METRICSMAP, SCOPE_RING = DEVICE_STAGES
+# a sub-scope of ``ct_update``, not a stage: the CT insert claim, run
+# only by a step whose batch has a pending insert, so a trace's count
+# of ``ct_claim`` op executions over its ``jit_serve*`` module count is
+# the share of steps that inserted (its time stays under ``ct_update``)
+SCOPE_CT_CLAIM = "ct_claim"
 
 # profiler span -> (thread, the per-packet interval it lies in;
 # None where no packet waits on it)

@@ -8,24 +8,37 @@ Reference behavior under test: ``bpf/lib/conntrack.h`` ct_lookup/
 ct_create probe loop (SURVEY.md §2a:90).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from cilium_tpu.core import TCP_ACK, TCP_SYN, make_batch
+from cilium_tpu.core.packets import (COL_FLAGS, COL_LEN, COL_PROTO,
+                                     TCP_FIN, TCP_RST)
 from cilium_tpu.datapath import CTTable
 from cilium_tpu.datapath.conntrack import (
     CT_ESTABLISHED,
     CT_NEW,
     CT_REPLY,
     KEY_WORDS,
+    LIFETIME_CLOSE,
+    LIFETIME_NONTCP,
     LIFETIME_SYN,
+    LIFETIME_TCP,
     N_CAND,
     N_CAND_INS,
     N_PROBE,
+    ST_CLOSING,
+    ST_ESTABLISHED,
     ST_FREE,
+    ST_SYN_SENT,
     V_EXPIRES,
+    V_RX_BYTES,
+    V_RX_PKTS,
     V_STATE,
+    V_TX_BYTES,
+    V_TX_PKTS,
     _fp_mix,
     _first_k,
     _fp_mix_np,
@@ -259,3 +272,209 @@ def test_candidate_slots_by_arithmetic(cap):
             np.testing.assert_array_equal(
                 np.asarray(_slots(jnp.asarray(h), pos[:, j], cap - 1)),
                 want[:, j])
+
+
+def _unconditioned_ct_update(ct, hdr, fwd, result, slot, is_reply,
+                             do_create, proxy_port, now, valid):
+    """``ct_update`` as it was before its claim went under
+    ``lax.cond(any(pending))``: every batch runs the fast claim loop."""
+    proto = hdr[:, COL_PROTO].astype(jnp.uint32)
+    flags = hdr[:, COL_FLAGS].astype(jnp.uint32)
+    length = hdr[:, COL_LEN].astype(jnp.uint32)
+    is_tcp = proto == 6
+    closing = is_tcp & ((flags & (TCP_FIN | TCP_RST)) != 0)
+    table, capacity = ct.table, ct.capacity
+    hit = (result != CT_NEW) & valid
+    hslot = jnp.where(hit, slot, 0)
+    old_state = table[hslot, V_STATE]
+    new_state = jnp.where(is_reply & (old_state == ST_SYN_SENT),
+                          ST_ESTABLISHED, old_state)
+    new_state = jnp.where(closing, ST_CLOSING, new_state)
+    upd_rows = jnp.where(hit, hslot, capacity)
+    table = table.at[upd_rows, V_STATE].max(
+        new_state.astype(jnp.uint32), mode="drop")
+    final_state = table[hslot, V_STATE]
+    lifetime = jnp.where(
+        final_state == ST_CLOSING, LIFETIME_CLOSE,
+        jnp.where(is_tcp,
+                  jnp.where(final_state >= ST_ESTABLISHED, LIFETIME_TCP,
+                            LIFETIME_SYN),
+                  LIFETIME_NONTCP)).astype(jnp.uint32)
+    table = table.at[upd_rows, V_EXPIRES].set(now + lifetime, mode="drop")
+    pkt_col = jnp.where(is_reply, V_RX_PKTS, V_TX_PKTS)
+    byte_col = jnp.where(is_reply, V_RX_BYTES, V_TX_BYTES)
+    table = table.at[upd_rows, pkt_col].add(1, mode="drop")
+    table = table.at[upd_rows, byte_col].add(length, mode="drop")
+
+    pending = do_create & (result == CT_NEW) & valid
+    init_state = jnp.where(is_tcp, ST_SYN_SENT, ST_ESTABLISHED)
+    init_life = jnp.where(is_tcp, LIFETIME_SYN, LIFETIME_NONTCP)
+    new_row = jnp.concatenate([fwd, jnp.stack([
+        init_state.astype(jnp.uint32), now + init_life.astype(jnp.uint32),
+        jnp.ones_like(length), jnp.zeros_like(length), length,
+        jnp.zeros_like(length), proxy_port.astype(jnp.uint32)],
+        axis=1)], axis=1)
+    fp = ct.fp
+    mask = capacity - 1
+    h = _hash(fwd)
+    win_fp = _fp_window(fp, h)
+    key_fp = _fp_mix(h)
+
+    def _claim(table, fp, pending, s, also_try=None):
+        stored = table[s]
+        claimable = ((stored[:, V_STATE] == ST_FREE)
+                     | (stored[:, V_EXPIRES] < now)
+                     | jnp.all(stored[:, :KEY_WORDS] == fwd, axis=1))
+        trying = pending & claimable
+        if also_try is not None:
+            trying = trying & also_try
+        table = table.at[jnp.where(trying, s, capacity)].set(
+            new_row, mode="drop")
+        won = trying & jnp.all(table[s][:, :KEY_WORDS] == fwd, axis=1)
+        fp = fp.at[jnp.where(won, s, capacity)].set(key_fp, mode="drop")
+        return table, fp, pending & ~won
+
+    cand_mask = (win_fp == 0) | (win_fp == key_fp[:, None])
+    pos, cand_valid = _first_k(cand_mask, N_CAND_INS)
+    for k in range(N_CAND_INS):
+        table, fp, pending = _claim(table, fp, pending,
+                                    _slots(h, pos[:, k], mask),
+                                    cand_valid[:, k])
+
+    def _full(args):
+        table, fp, pending = args
+        for step in range(N_PROBE):
+            table, fp, pending = _claim(table, fp, pending,
+                                        _slots(h, step, mask))
+        return table, fp, pending
+
+    table, fp, pending = jax.lax.cond(
+        jnp.any(pending), _full, lambda a: a, (table, fp, pending))
+    dropped = ct.dropped + jnp.sum(pending).astype(jnp.uint32)
+    return CTTable(table=table, fp=fp, dropped=dropped)
+
+
+_unconditioned_ct_update_jit = jax.jit(_unconditioned_ct_update)
+
+CLAIM_CAPS = [8, 32, 1 << 10, 1 << 19]  # 8: the per-slot window path
+
+
+def _mixed_flows(n, rng):
+    """``n`` distinct flows, TCP and UDP, with SYN, ACK, FIN and RST
+    rows: NEW inserts, refreshes and closes in one batch."""
+    flags = [TCP_SYN, TCP_ACK, TCP_ACK | TCP_FIN, TCP_RST]
+    rows = []
+    for i in range(n):
+        proto = 6 if rng.random() < 0.8 else 17
+        rows.append(dict(
+            src=f"10.{i // 62500}.{i // 250 % 250}.{i % 250 + 1}",
+            dst=f"10.200.0.{rng.integers(1, 4)}",
+            sport=int(rng.integers(1024, 60000)),
+            dport=int(rng.choice([53, 443, 5432])), proto=proto,
+            flags=int(rng.choice(flags)) if proto == 6 else 0,
+            length=int(rng.integers(40, 1500))))
+    return make_batch(rows).data
+
+
+def _update_both(ct, ref, hdr, do_create, valid, now):
+    """One step of ``ct_update_jit`` and of the unconditioned copy from
+    the same state; -> (new state, reference state, pending count)."""
+    hdr = jnp.asarray(hdr)
+    fwd, rev = ct_keys_jit(hdr)
+    res, slot, rep = ct_lookup_jit(ct, fwd, rev, now)
+    pending = int(np.sum(np.asarray(do_create) & (np.asarray(res) == CT_NEW)
+                         & np.asarray(valid)))
+    args = (hdr, fwd, res, slot, rep, jnp.asarray(do_create),
+            jnp.zeros(len(hdr), jnp.uint32), now, jnp.asarray(valid))
+    ref = _unconditioned_ct_update_jit(ref, *args)
+    ct = ct_update_jit(ct, *args[:-2], now=now, valid=args[-1])
+    return ct, ref, pending
+
+
+def _assert_same_ct(a, b):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _copy_ct(ct):
+    return jax.tree.map(lambda x: jnp.array(np.asarray(x)), ct)
+
+
+@pytest.mark.parametrize("cap", CLAIM_CAPS)
+def test_no_pending_batch_leaves_table_fp_dropped_unchanged(cap):
+    """A batch with no pending insert (denied NEW rows, valid-masked
+    padding, hits) writes no claim: ``fp`` and ``dropped`` come back
+    bit-identical, the table too but for the hit rows' refresh, which
+    equals the unconditioned claim loop's."""
+    rng = np.random.default_rng(cap)
+    n_seed = min(cap // 2, 64)
+    flows = _mixed_flows(2 * n_seed, rng)
+    flows[:, COL_PROTO], flows[:, COL_FLAGS] = 6, TCP_SYN
+    now = jnp.uint32(100)
+    ct = CTTable.create(cap)
+    seeded = flows[:n_seed]
+    ct, _ref, n_ins = _update_both(ct, _copy_ct(ct), seeded,
+                                   np.ones(n_seed, bool),
+                                   np.ones(n_seed, bool), now)
+    assert n_ins == n_seed and int(np.asarray(ct.dropped)) == 0
+    fresh = flows[n_seed:]
+    denied = np.arange(n_seed) % 2 == 0  # the rest is padding
+    later = jnp.uint32(101)
+
+    before = _copy_ct(ct)
+    ct, ref, pending = _update_both(ct, _copy_ct(ct), fresh, ~denied,
+                                    denied, later)
+    assert pending == 0
+    _assert_same_ct(ct, before)
+    _assert_same_ct(ref, before)
+
+    hits = seeded.copy()
+    hits[:, COL_FLAGS] = TCP_ACK
+    mix = np.concatenate([hits, fresh])
+    valid = np.concatenate([np.ones(n_seed, bool), denied])
+    do_create = np.concatenate([np.ones(n_seed, bool), ~denied])
+    ct, ref, pending = _update_both(ct, _copy_ct(ct), mix, do_create,
+                                    valid, later)
+    assert pending == 0
+    _assert_same_ct(ct, ref)
+    np.testing.assert_array_equal(np.asarray(ct.fp), np.asarray(before.fp))
+    assert int(np.asarray(ct.dropped)) == int(np.asarray(before.dropped))
+    table, old = np.asarray(ct.table), np.asarray(before.table)
+    changed = np.flatnonzero((table != old).any(axis=1))
+    assert 0 < len(changed) <= n_seed
+    live = old[changed, V_STATE] != ST_FREE
+    assert live.all()  # refreshed entries only, no claimed slot
+
+
+@pytest.mark.parametrize("cap", CLAIM_CAPS)
+def test_claim_skip_matches_unconditioned_claim_loop(cap):
+    """Seeded steps mixing insert and no-insert batches — duplicate
+    keys in a batch, expired twins re-claimed, windows full enough to
+    drop — leave the same table, fp and drop count as the claim loop
+    run on every batch."""
+    rng = np.random.default_rng(cap + 1)
+    n_flows = min(3 * cap, 2048)
+    batch = min(2 * cap, 256)
+    universe = _mixed_flows(n_flows, rng)
+    ct = CTTable.create(cap)
+    ref = _copy_ct(ct)
+    now, kinds = 100, set()
+    for step in range(12):
+        pick = rng.integers(0, n_flows, batch)
+        pick[1::8] = pick[::8]  # duplicate keys within the batch
+        if step % 3 == 1:  # a no-insert batch
+            do_create = np.zeros(batch, bool)
+        else:
+            do_create = rng.random(batch) < 0.8
+        valid = rng.random(batch) < 0.9
+        ct, ref, pending = _update_both(ct, ref, universe[pick],
+                                        do_create, valid, jnp.uint32(now))
+        _assert_same_ct(ct, ref)
+        kinds.add(pending > 0)
+        # expire most entries now and then: their keys come back as
+        # expired twins, other keys claim their slots
+        now += int(rng.integers(1, 20)) + (LIFETIME_SYN if step % 4 == 2
+                                           else 0)
+    assert kinds == {True, False}
+    if cap <= 32:
+        assert int(np.asarray(ct.dropped)) > 0  # a window ran full

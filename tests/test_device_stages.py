@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import hashlib
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -24,9 +25,11 @@ from cilium_tpu.datapath import datapath_step_jit
 from cilium_tpu.monitor.ring import (EventRing, serve_step_jit,
                                      serve_step_packed_jit,
                                      serve_superbatch_jit)
-from cilium_tpu.obs.trace import DEVICE_STAGES, SCOPE_UNPACK
+from cilium_tpu.obs.trace import (DEVICE_STAGES, SCOPE_CT_CLAIM,
+                                  SCOPE_CT_UPDATE, SCOPE_UNPACK)
 from cilium_tpu.testing.fixtures import bench_traffic, build_world
 
+ROOT = Path(__file__).resolve().parents[1]
 N = 256
 NOW = (100, 130)  # two steps: the second hits the CT entries of the first
 
@@ -120,3 +123,24 @@ def test_every_device_stage_names_ops_in_compiled_hlo(path):
     assert want <= scopes, sorted(want - scopes)
     if path != "packed":
         assert SCOPE_UNPACK not in scopes
+
+
+@pytest.mark.parametrize("path", ["wide", "packed", "superbatch"])
+def test_ct_claim_ops_split_under_ct_update(path, monkeypatch):
+    """The CT insert claim runs under ``ct_claim`` inside the
+    ``ct_update`` stage, so the benchmark's trace split, which takes an
+    op's innermost stage, counts the claim's time as ``ct_update``."""
+    monkeypatch.syspath_prepend(str(ROOT / "benchmark"))
+    import tracesplit
+
+    world = _world()
+    fn, args, kw = _call(path, world.state, EventRing.create(1 << 10),
+                         _traffic(world), jnp.uint32(NOW[0]),
+                         jnp.uint32(0))
+    hlo = fn.lower(*args, **kw).compile().as_text()
+    claims = [name for name in re.findall(r'op_name="([^"]*)"', hlo)
+              if SCOPE_CT_CLAIM in name.split("/")]
+    assert claims
+    assert {tracesplit.scope_of(name) for name in claims} == {
+        SCOPE_CT_UPDATE}
+    assert tracesplit.DEVICE_STAGES == DEVICE_STAGES

@@ -12,6 +12,7 @@ worker imports this file.  Keep these tests in this one file.
 """
 
 import re
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -24,12 +25,14 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 from cilium_tpu.agent.daemon import DaemonConfig
 from cilium_tpu.core.packets import N_COLS, PACKED_COLS
 from cilium_tpu.datapath.conntrack import (KEY_WORDS, N_PROBE, CTTable,
-                                           ct_lookup_jit)
+                                           ct_lookup_jit, ct_update_jit)
 from cilium_tpu.datapath.verdict import datapath_step_packed_jit
 from cilium_tpu.monitor.ring import EventRing, serve_step_packed_jit
+from cilium_tpu.obs.trace import DEVICE_STAGES, SCOPE_CT_CLAIM
 from cilium_tpu.parallel.mesh import make_sharded_serve_step
 from cilium_tpu.testing.fixtures import build_world
 
+ROOT = Path(__file__).resolve().parents[1]
 HBM_BYTES = 16 << 30  # one v5e chip
 TOP_RUNG = DaemonConfig.serving_bucket_ladder[-1]
 RING_CAPACITY = 1 << 15  # Daemon.start_serving's default
@@ -142,10 +145,10 @@ def test_sharded_serve_step_2x2(topo, state_shapes):
     assert "all-reduce" in compiled.as_text()  # the psum-ed counters
 
 
-def _fast_path_gathers(hlo: str):
-    """(result shape, operand shape) of every gather outside the
-    branches of a ``conditional``: the fast path, with the
-    ``lax.cond`` fallbacks left out."""
+def _computations(hlo: str):
+    """(body lines by computation name, the entry computation's name,
+    the computations a ``conditional`` reaches: its branches and all
+    they call) of a compiled module's text."""
     called = re.compile(r"(?:calls|to_apply|body|condition|"
                         r"branch_computations)=(\{[^}]*\}|%[\w.-]+)")
 
@@ -153,30 +156,48 @@ def _fast_path_gathers(hlo: str):
         return [name for line in lines for group in called.findall(line)
                 for name in re.findall(r"%([\w.-]+)", group)]
 
-    comps, shapes, cur = {}, {}, None
+    comps, entry, cur = {}, None, None
     for line in hlo.splitlines():
-        head = re.match(r"(?:ENTRY )?%([\w.-]+) \(", line)
+        head = re.match(r"(ENTRY )?%([\w.-]+) \(", line)
         if head and line.rstrip().endswith("{"):
-            cur = comps.setdefault(head.group(1), [])
+            cur = comps.setdefault(head.group(2), [])
+            entry = head.group(2) if head.group(1) else entry
         elif cur is not None:
             cur.append(line)
+    todo = callees(line for body in comps.values() for line in body
+                   if " conditional(" in line)
+    branches = set()
+    while todo:
+        name = todo.pop()
+        if name not in branches:
+            branches.add(name)
+            todo += callees(comps.get(name, ()))
+    return comps, entry, branches
+
+
+def _ops(lines, kind: str):
+    """(result shape, first operand, line) of every ``kind`` op."""
+    op = re.compile(r"\s*(?:ROOT )?%[\w.-]+ = (\w+\[[\d,]*\])\S* "
+                    + re.escape(kind) + r"\(%([\w.-]+)")
+    return [(m.group(1), m.group(2), line) for line in lines
+            for m in [op.match(line)] if m]
+
+
+def _fast_path_ops(hlo: str, kind: str):
+    """(result shape, operand shape) of every ``kind`` op outside the
+    branches of a ``conditional``: the fast path, with the
+    ``lax.cond`` branches left out."""
+    comps, _entry, branches = _computations(hlo)
+    shapes = {}
+    for body in comps.values():
+        for line in body:
             m = re.match(r"\s*(?:ROOT )?%([\w.-]+) = (\w+\[[\d,]*\])",
                          line)
             if m:
                 shapes[m.group(1)] = m.group(2)
-    todo = callees(line for body in comps.values() for line in body
-                   if " conditional(" in line)
-    slow = set()
-    while todo:
-        name = todo.pop()
-        if name not in slow:
-            slow.add(name)
-            todo += callees(comps.get(name, ()))
-    return [(m.group(1), shapes.get(m.group(2)))
-            for name, body in comps.items() if name not in slow
-            for m in (re.match(r"\s*(?:ROOT )?%[\w.-]+ = (\w+\[[\d,]*\])"
-                               r"\S* gather\(%([\w.-]+)", line)
-                      for line in body) if m]
+    return [(res, shapes.get(operand))
+            for name, body in comps.items() if name not in branches
+            for res, operand, _line in _ops(body, kind)]
 
 
 def test_ct_lookup_fingerprint_probe_gathers_blocks(one_chip):
@@ -190,9 +211,49 @@ def test_ct_lookup_fingerprint_probe_gathers_blocks(one_chip):
     compiled = ct_lookup_jit.lower(_on(ct, one_chip), keys, keys,
                                    _scalar(one_chip)).compile()
     _check(compiled)
-    gathers = _fast_path_gathers(compiled.as_text())
+    gathers = _fast_path_ops(compiled.as_text(), "gather")
     operands = {op for _res, op in gathers}
     assert f"u32[{CT_DEFAULT},17]" in operands, gathers  # candidate rows
     assert f"u32[{CT_DEFAULT // N_PROBE},{N_PROBE}]" in operands, gathers
     assert f"u32[{CT_DEFAULT}]" not in operands, gathers
     assert f"s32[{TOP_RUNG},{N_PROBE}]" not in operands, gathers
+
+
+def test_ct_update_claims_only_under_cond(one_chip, monkeypatch):
+    """``ct_update``'s insert claim runs inside a ``conditional``'s
+    branch, scoped ``ct_claim``: a batch with no pending insert issues
+    no scatter into the table or ``fp``, and both pass through the
+    conditional in place, with no copy in the entry computation.
+    ``ct_claim`` is no stage of the benchmark's trace split."""
+    monkeypatch.syspath_prepend(str(ROOT / "benchmark"))
+    import tracesplit
+
+    ct = jax.eval_shape(lambda: CTTable.create(CT_DEFAULT))
+
+    def rows(*cols, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct((TOP_RUNG,) + cols, dtype,
+                                    sharding=one_chip)
+
+    compiled = ct_update_jit.lower(
+        _on(ct, one_chip), rows(N_COLS), rows(KEY_WORDS),
+        rows(dtype=jnp.int32), rows(dtype=jnp.int32),
+        rows(dtype=jnp.bool_), rows(dtype=jnp.bool_), rows(),
+        _scalar(one_chip), rows(dtype=jnp.bool_)).compile()
+    _check(compiled)
+    hlo = compiled.as_text()
+    ct_shapes = {f"u32[{CT_DEFAULT},17]", f"u32[{CT_DEFAULT}]"}
+    fast = [s for s in _fast_path_ops(hlo, "scatter") if s[0] in ct_shapes]
+    assert not fast, fast
+    comps, entry, branches = _computations(hlo)
+    copies = [res for res, _op, _line in _ops(comps[entry], "copy")
+              if res in ct_shapes]
+    assert not copies, copies
+    claims = [line for name in branches
+              for res, _op, line in _ops(comps[name], "scatter")
+              if res in ct_shapes]
+    assert claims
+    for line in claims:
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert SCOPE_CT_CLAIM in op_name.split("/"), op_name
+    assert tracesplit.DEVICE_STAGES == DEVICE_STAGES
+    assert SCOPE_CT_CLAIM not in DEVICE_STAGES
